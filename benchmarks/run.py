@@ -32,6 +32,8 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.launch.compile_cache import use_compile_cache
+
 # deterministic metrics collected during the run for --json (the CI
 # regression gate compares them against benchmarks/baseline.json), the
 # per-metric provenance map written alongside them, and the serve
@@ -1259,6 +1261,7 @@ def main() -> None:
                         "--profile; gate it with "
                         "scripts/check_calibration.py)")
     args = p.parse_args()
+    use_compile_cache()
     figs = _select_figs(args.only)
     if args.profile or args.calibration_out:
         def _profile(quick=False):

@@ -14,6 +14,7 @@ import argparse
 import jax
 
 from repro.configs.base import ModelConfig, ParallelConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.build import build_model
 from repro.runtime.cluster import (ClusterConfig, ClusterServer, Replica,
                                    ROUTERS)
@@ -32,6 +33,7 @@ def main():
     p.add_argument("--requests", type=int, default=48,
                    help="trace size for the disaggregation comparison")
     args = p.parse_args()
+    use_compile_cache()
 
     cfg = ModelConfig(name="tiny", family="dense", num_layers=2, d_model=64,
                       num_heads=4, num_kv_heads=2, head_dim=16, d_ff=128,

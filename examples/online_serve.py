@@ -12,6 +12,7 @@ import argparse
 import jax
 
 from repro.configs.base import ModelConfig, ParallelConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.build import build_model
 from repro.runtime.engine import Engine
 from repro.runtime.requests import poisson_arrivals, sharegpt_like_trace
@@ -29,6 +30,7 @@ def main():
     p.add_argument("--deadline", type=float, default=None,
                    help="per-request e2e SLO in virtual ticks")
     args = p.parse_args()
+    use_compile_cache()
 
     cfg = ModelConfig(name="tiny", family="dense", num_layers=2, d_model=64,
                       num_heads=4, num_kv_heads=2, head_dim=16, d_ff=128,

@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, ParallelConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.build import build_model
 from repro.runtime.engine import Engine
 from repro.runtime.requests import Request
@@ -17,6 +18,7 @@ from repro.training.train_step import make_train_step
 
 
 def main():
+    use_compile_cache()
     cfg = ModelConfig(name="quickstart", family="dense", num_layers=2,
                       d_model=128, num_heads=4, num_kv_heads=2, head_dim=32,
                       d_ff=256, vocab_size=256, dtype="float32")

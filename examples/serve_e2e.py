@@ -12,6 +12,7 @@ import jax
 
 from repro.configs import get_config
 from repro.configs.base import ParallelConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.build import build_model
 from repro.runtime.engine import Engine
 from repro.runtime.requests import sharegpt_like_trace
@@ -25,6 +26,7 @@ def main():
     p.add_argument("--weave-off", action="store_true")
     p.add_argument("--chunk", type=int, default=128)
     args = p.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch).reduced()
     pcfg = ParallelConfig(tokenweave=not args.weave_off, comm_mode="fused",

@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.configs.base import ParallelConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.build import build_model
 from repro.runtime.engine import Engine
 from repro.runtime.requests import Request
@@ -66,6 +67,7 @@ def main():
     p.add_argument("--paged-off", action="store_true",
                    help="legacy slot engine (no paging, no prefix cache)")
     args = p.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch).reduced()
     pcfg = ParallelConfig(tokenweave=True, comm_mode="fused", remat=False,

@@ -18,6 +18,7 @@ from collections import defaultdict
 import jax
 
 from repro.configs.base import ModelConfig, ParallelConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.build import build_model
 from repro.obs import (TERMINAL_PHASES, TraceRecorder, export_chrome_trace,
                        validate_chrome_trace, weave_counts_from_trace)
@@ -38,6 +39,7 @@ def main():
     p.add_argument("--follow", type=int, default=0, metavar="RID",
                    help="request whose weave-decision log to walk")
     args = p.parse_args()
+    use_compile_cache()
 
     cfg = ModelConfig(name="tiny", family="dense", num_layers=2, d_model=64,
                       num_heads=4, num_kv_heads=2, head_dim=16, d_ff=128,

@@ -1,6 +1,4 @@
 """repro: TokenWeave — efficient compute-communication overlap for distributed
 LLM inference — reproduced and extended as a TPU-native JAX framework."""
 
-from repro import compat as _compat  # noqa: F401  (installs jax shims)
-
 __version__ = "0.1.0"

@@ -36,7 +36,6 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.splitting import ring_channels
 from repro.distributed.context import CommCtx, token_shard_slice
@@ -48,15 +47,9 @@ from repro.layers.norms import residual_rmsnorm_unfused, rms_norm
 def _ring_supported(ctx: CommCtx, reduce_input: bool, weight_post) -> bool:
     """Fallback ladder for mode="ring" (DESIGN.md §2): the one-kernel ring
     path needs (a) a genuine reduction to fold in (``reduce_input``), (b)
-    no sandwich post-norm (the kernel fuses exactly add+norm), (c) Pallas
-    enabled, and (d) a backend whose interpreter can emulate remote DMAs
-    when interpreting — jax < 0.5's CPU interpreter (no
-    ``pltpu.InterpretParams``) cannot, so CI gates to the composition."""
-    if not (reduce_input and weight_post is None and ctx.use_pallas):
-        return False
-    if ctx.interpret and not hasattr(pltpu, "InterpretParams"):
-        return False
-    return True
+    no sandwich post-norm (the kernel fuses exactly add+norm), and (c)
+    Pallas enabled."""
+    return reduce_input and weight_post is None and ctx.use_pallas
 
 
 def comm_norm(x, residual, weight, *, ctx: CommCtx, reduce_input: bool = True,

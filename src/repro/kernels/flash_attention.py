@@ -40,15 +40,15 @@ def _flash_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
     q = q_ref[...].astype(jnp.float32)        # (bq, g, dh)
     k = k_ref[...].astype(jnp.float32)        # (bk, dh)
     v = v_ref[...].astype(jnp.float32)        # (bk, dh)
-    qp = qpos_ref[...]                        # (bq,)
-    kp = kpos_ref[...]                        # (bk,)
+    qp = qpos_ref[...]                        # (bq, 1)
+    kp = kpos_ref[...]                        # (1, bk)
 
     logits = jnp.einsum("qgd,kd->qgk", q, k) * sm_scale
-    mask = kp[None, :] >= 0
+    mask = kp >= 0
     if causal:
-        mask &= qp[:, None] >= kp[None, :]
+        mask &= qp >= kp
     if window > 0:
-        mask &= (qp[:, None] - kp[None, :]) < window
+        mask &= (qp - kp) < window
     logits = jnp.where(mask[:, None, :], logits, NEG_INF)
 
     m_prev = m_ref[...]                       # (bq, g)
@@ -74,6 +74,12 @@ def _flash_single(q, k, v, qpos, kpos, *, causal, window, sm_scale,
     sk = k.shape[0]
     bq = min(block_q, sq)
     bk = min(block_kv, sk)
+    # the f32 (bq, g, bk) logits tile and its exp live in VMEM: with a wide
+    # GQA group, halve the kv block until the tile is at most 2^20 entries
+    # (the v5e compiler refuses bq=512, g=5, bk=1024 at its 16 MiB scoped
+    # VMEM default)
+    while bq * g * bk > 2 ** 20 and bk > 128:
+        bk //= 2
     pq, pk = (-sq) % bq, (-sk) % bk
     if pq:
         q = jnp.pad(q, ((0, pq), (0, 0), (0, 0)))
@@ -86,12 +92,14 @@ def _flash_single(q, k, v, qpos, kpos, *, causal, window, sm_scale,
 
     kernel = functools.partial(_flash_kernel, causal=causal, window=window,
                                sm_scale=sm_scale, num_kv_blocks=nk)
+    # positions ride as a (Sq, 1) column and a (1, Sk) row: 2-D blocks
+    # whose tiling matches XLA's layout, broadcasting to the (bq, bk) mask
     out = pl.pallas_call(
         kernel,
         grid=(nq, nk),
         in_specs=[
-            pl.BlockSpec((bq,), lambda i, j: (i,)),
-            pl.BlockSpec((bk,), lambda i, j: (j,)),
+            pl.BlockSpec((bq, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, bk), lambda i, j: (0, j)),
             pl.BlockSpec((bq, g, dh), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((bk, dh), lambda i, j: (j, 0)),
             pl.BlockSpec((bk, dh), lambda i, j: (j, 0)),
@@ -104,7 +112,7 @@ def _flash_single(q, k, v, qpos, kpos, *, causal, window, sm_scale,
             pltpu.VMEM((bq, g, dh), jnp.float32),   # accumulator
         ],
         interpret=interpret,
-    )(qpos, kpos, q, k, v)
+    )(qpos[:, None], kpos[None, :], q, k, v)
     return out[:sq]
 
 

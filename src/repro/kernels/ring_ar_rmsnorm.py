@@ -26,9 +26,7 @@ their semaphores), mapped from a plan entry's SM-equivalent ``budget`` by
 
 Numerics are pinned against kernels/ref.ring_ar_rmsnorm_ref and the
 unfused vanilla composition by tests/test_fused_path.py (in-process and
-subprocess-distributed); on backends whose Pallas interpreter cannot
-emulate remote DMAs (jax < 0.5 CPU) the ring mode gates to the fallback
-composition instead of running this kernel.
+subprocess-distributed).
 """
 from __future__ import annotations
 
@@ -45,8 +43,18 @@ def _kernel(x_hbm, res_ref, w_ref, out_hbm, res_out_ref, comm_hbm,
             *, n_dev: int, chunk: int, eps: float, axis_name: str,
             channels: int):
     me = jax.lax.axis_index(axis_name)
-    right = jax.lax.rem(me + 1, n_dev)
-    left = jax.lax.rem(me - 1 + n_dev, n_dev)
+    # neighbours named by their coordinate on the ring's axis alone, so on
+    # a multi-axis mesh every other coordinate stays this device's own
+    right = {axis_name: jax.lax.rem(me + 1, n_dev)}
+    left = {axis_name: jax.lax.rem(me - 1 + n_dev, n_dev)}
+    if n_dev > 1:
+        # both neighbours must be inside this kernel before a remote DMA or
+        # semaphore signal of ours can reach them
+        barrier = pltpu.get_barrier_semaphore()
+        for nb in (left, right):
+            pltpu.semaphore_signal(barrier, 1, device_id=nb,
+                                   device_id_type=pltpu.DeviceIdType.MESH)
+        pltpu.semaphore_wait(barrier, 2)
 
     def dma_in(idx, dst):
         """x_hbm[idx*chunk : (idx+1)*chunk] -> dst (VMEM)."""
@@ -68,7 +76,7 @@ def _kernel(x_hbm, res_ref, w_ref, out_hbm, res_out_ref, comm_hbm,
             src_ref=send_vmem,
             dst_ref=comm_hbm.at[slot],
             send_sem=send_sem.at[0], recv_sem=recv_sem.at[slot],
-            device_id=(right,), device_id_type=pltpu.DeviceIdType.MESH)
+            device_id=right, device_id_type=pltpu.DeviceIdType.MESH)
         rcp.start()
         rcp.wait()
         # arrival of chunk (me - s - 2) from the left neighbor
@@ -78,7 +86,7 @@ def _kernel(x_hbm, res_ref, w_ref, out_hbm, res_out_ref, comm_hbm,
         cp.wait()
         # slot consumed: free it for the left neighbor (phase-1 tail signals
         # are drained by phase-3's first two sends — see pairing note below)
-        pltpu.semaphore_signal(free_sem.at[slot], 1, device_id=(left,),
+        pltpu.semaphore_signal(free_sem.at[slot], 1, device_id=left,
                                device_id_type=pltpu.DeviceIdType.MESH)
         idx = jax.lax.rem(me - s - 2 + 2 * n_dev, n_dev)
         dma_in(idx, chunk_vmem)
@@ -120,7 +128,7 @@ def _kernel(x_hbm, res_ref, w_ref, out_hbm, res_out_ref, comm_hbm,
             src_ref=send_vmem,
             dst_ref=comm_hbm.at[slot],
             send_sem=send_sem.at[0], recv_sem=recv_sem.at[slot],
-            device_id=(right,), device_id_type=pltpu.DeviceIdType.MESH)
+            device_id=right, device_id_type=pltpu.DeviceIdType.MESH)
         rcp.start()
         rcp.wait()
         cp = pltpu.make_async_copy(comm_hbm.at[slot], chunk_vmem,
@@ -128,7 +136,7 @@ def _kernel(x_hbm, res_ref, w_ref, out_hbm, res_out_ref, comm_hbm,
         cp.start()
         cp.wait()
         if s + channels < n_dev - 1:
-            pltpu.semaphore_signal(free_sem.at[slot], 1, device_id=(left,),
+            pltpu.semaphore_signal(free_sem.at[slot], 1, device_id=left,
                                    device_id_type=pltpu.DeviceIdType.MESH)
         idx = jax.lax.rem(me - s - 1 + 2 * n_dev, n_dev)
         ocp = pltpu.make_async_copy(chunk_vmem,
@@ -182,13 +190,10 @@ def ring_fused_ar_rmsnorm(x, residual, weight, *, axis_name: str,
             pltpu.SemaphoreType.DMA((channels,)),
             pltpu.SemaphoreType.REGULAR((channels,)),
         ],
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None)
-                                )(collective_id=7),
-        # older pallas has no InterpretParams dataclass; plain True selects
-        # the same interpreter
-        interpret=(pltpu.InterpretParams()
-                   if hasattr(pltpu, "InterpretParams") else True)
-        if interpret else False,
+        # the barrier semaphore is keyed by collective_id; a lone device
+        # takes no barrier and may name none
+        compiler_params=pltpu.CompilerParams(
+            collective_id=7 if n_dev > 1 else None),
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(x, residual, weight)
     return out, new_res

@@ -4,6 +4,9 @@ from __future__ import annotations
 import dataclasses
 from typing import Any
 
+import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
+
 from repro.configs.base import ModelConfig, ParallelConfig
 from repro.models import encdec, hybrid, mamba_model, transformer
 
@@ -29,9 +32,16 @@ class ModelApi:
     ep: int = 1
 
     # ---- host-side ------------------------------------------------------
-    def init(self, key):
-        return self.mod.init_params(key, self.cfg, self.pcfg, self.tp,
-                                    self.ep)
+    def init(self, key, mesh=None):
+        """Parameters from ``key``.  With a ``mesh`` they are generated
+        under jit straight into their ``specs()`` sharding, so each device
+        makes only its own shard and no device ever holds the whole
+        model."""
+        if mesh is None:
+            return self.mod.init_params(key, self.cfg, self.pcfg, self.tp,
+                                        self.ep)
+        return jax.jit(self.init, out_shardings=named_shardings(
+            mesh, self.specs()))(key)
 
     def specs(self):
         return self.mod.param_specs(self.cfg, self.pcfg)
@@ -78,6 +88,12 @@ class ModelApi:
         DESIGN.md §6)."""
         return self.mod.packed_step(params, tokens, cache, cfg=self.cfg,
                                     pcfg=self.pcfg, positions=positions, **kw)
+
+
+def named_shardings(mesh, specs):
+    """PartitionSpec pytree -> NamedSharding pytree on ``mesh``."""
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                        is_leaf=lambda s: isinstance(s, P))
 
 
 def build_model(cfg: ModelConfig, pcfg: ParallelConfig, tp: int,

@@ -129,12 +129,13 @@ def layer_param_specs(cfg: ModelConfig):
 def init_params(key, cfg: ModelConfig, pcfg: ParallelConfig, tp: int,
                 ep: int = 1):
     ke, kl = jax.random.split(key)
-    layers = [init_layer_params(k, cfg, tp, ep)
-              for k in jax.random.split(kl, cfg.num_layers)]
+    keys = jax.random.split(kl, cfg.num_layers)
     if use_scan(cfg, pcfg):
-        layers = jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
+        # generated already stacked: never a per-layer list AND its stack
+        layers = jax.vmap(lambda k: init_layer_params(k, cfg, tp, ep))(keys)
     else:
-        layers = {f"layer_{i}": lp for i, lp in enumerate(layers)}
+        layers = {f"layer_{i}": init_layer_params(k, cfg, tp, ep)
+                  for i, k in enumerate(keys)}
     return {
         "embedding": E.init_embedding_params(ke, cfg, tp),
         "norm_first": jnp.ones((1, cfg.d_model), jnp.dtype(cfg.dtype)),

@@ -49,11 +49,11 @@ from typing import Dict, List
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from repro.core.splitting import pad_to_multiple
 from repro.models import transformer as TRX
-from repro.models.build import ModelApi
+from repro.models.build import ModelApi, named_shardings
 from repro.obs.attribution import Attributor, WeaveAttribution
 from repro.obs.metrics import MetricsRegistry, percentile as _percentile
 from repro.obs.profiler import WallClockProfiler
@@ -381,13 +381,17 @@ class Engine:
                 scfg.effective_num_blocks, scfg.block_size,
                 scfg.max_blocks_per_req,
                 prefix_caching=scfg.prefix_caching)
-            cache = PG.init_paged_cache(scfg.effective_num_blocks,
-                                        scfg.block_size, api.cfg, api.tp,
-                                        api.pcfg)
+
+            def make_cache():
+                return PG.init_paged_cache(scfg.effective_num_blocks,
+                                           scfg.block_size, api.cfg, api.tp,
+                                           api.pcfg)
             cspec = PG.paged_cache_specs(api.cfg, api.pcfg)
         else:
             self.block_mgr = None
-            cache = api.init_cache(scfg.max_batch, scfg.max_len)
+
+            def make_cache():
+                return api.init_cache(scfg.max_batch, scfg.max_len)
             cspec = api.cache_specs()
         self.sched = Scheduler(
             scfg, block_mgr=self.block_mgr,
@@ -400,9 +404,10 @@ class Engine:
         # disaggregated serving (DESIGN.md §11): requests parked by
         # ``_park_for_handoff`` wait here for the cluster to migrate them
         self.handoff_ready: List[Handoff] = []
-        self.cache = jax.device_put(
-            cache, jax.tree.map(lambda s: NamedSharding(mesh, s), cspec,
-                                is_leaf=lambda s: isinstance(s, P)))
+        # built on the mesh in its final sharding: no device ever holds
+        # the whole cache on its way to being placed
+        self.cache = jax.jit(make_cache, out_shardings=named_shardings(
+            mesh, cspec))()
         self._cspec = cspec
 
     def _next_key(self):
@@ -443,6 +448,18 @@ class Engine:
     # ------------------------------------------------------------------
     # jitted step functions
     # ------------------------------------------------------------------
+    def _jit_step(self, fn, name: str, n_plain: int, n_out: int):
+        """shard_map + jit one step body: params and cache in their specs,
+        ``n_plain`` replicated host inputs, ``n_out`` replicated outputs
+        followed by the (donated) cache.  ``name`` is the step's stable name
+        in compile logs and device traces."""
+        fn.__name__ = name
+        sm = jax.shard_map(
+            fn, mesh=self.mesh,
+            in_specs=(self._pspec, self._cspec) + (P(),) * n_plain,
+            out_specs=(P(),) * n_out + (self._cspec,), check_vma=False)
+        return jax.jit(sm, donate_argnums=(1,))
+
     def _prefill_fn(self, b_sel: int, chunk: int):
         key = ("prefill", b_sel, chunk)
         if key in self._jit_cache:
@@ -483,12 +500,7 @@ class Engine:
                          top_k=self.top_k, top_p=self.top_p, key=rng)
             return tok, new_cache
 
-        sm = jax.shard_map(
-            fn, mesh=self.mesh,
-            in_specs=(self._pspec, self._cspec, P(), P(), P(), P(), P(),
-                      P()),
-            out_specs=(P(), self._cspec), check_vma=False)
-        jfn = jax.jit(sm, donate_argnums=(1,))
+        jfn = self._jit_step(fn, f"prefill_b{b_sel}_s{chunk}", 6, 1)
         self._jit_cache[key] = jfn
         return jfn
 
@@ -514,11 +526,7 @@ class Engine:
                          top_k=self.top_k, top_p=self.top_p, key=rng)
             return tok, new_pool
 
-        sm = jax.shard_map(
-            fn, mesh=self.mesh,
-            in_specs=(self._pspec, self._cspec, P(), P(), P(), P(), P()),
-            out_specs=(P(), self._cspec), check_vma=False)
-        jfn = jax.jit(sm, donate_argnums=(1,))
+        jfn = self._jit_step(fn, f"paged_prefill_b{b_sel}_s{chunk}", 5, 1)
         self._jit_cache[key] = jfn
         return jfn
 
@@ -538,11 +546,7 @@ class Engine:
                          top_k=self.top_k, top_p=self.top_p, key=rng)
             return tok, new_cache
 
-        sm = jax.shard_map(
-            fn, mesh=self.mesh,
-            in_specs=(self._pspec, self._cspec, P(), P(), P()),
-            out_specs=(P(), self._cspec), check_vma=False)
-        jfn = jax.jit(sm, donate_argnums=(1,))
+        jfn = self._jit_step(fn, f"decode_b{self.scfg.max_batch}", 3, 1)
         self._jit_cache[key] = jfn
         return jfn
 
@@ -562,11 +566,8 @@ class Engine:
                          top_k=self.top_k, top_p=self.top_p, key=rng)
             return tok, new_pool
 
-        sm = jax.shard_map(
-            fn, mesh=self.mesh,
-            in_specs=(self._pspec, self._cspec, P(), P(), P(), P()),
-            out_specs=(P(), self._cspec), check_vma=False)
-        jfn = jax.jit(sm, donate_argnums=(1,))
+        jfn = self._jit_step(fn, f"paged_decode_b{self.scfg.max_batch}", 4,
+                             1)
         self._jit_cache[key] = jfn
         return jfn
 
@@ -587,11 +588,7 @@ class Engine:
                 top_k=self.top_k, top_p=self.top_p)
             return n_acc, emit, new_cache
 
-        sm = jax.shard_map(
-            fn, mesh=self.mesh,
-            in_specs=(self._pspec, self._cspec, P(), P(), P(), P()),
-            out_specs=(P(), P(), self._cspec), check_vma=False)
-        jfn = jax.jit(sm, donate_argnums=(1,))
+        jfn = self._jit_step(fn, f"verify_s{s_v}", 4, 2)
         self._jit_cache[key] = jfn
         return jfn
 
@@ -611,11 +608,7 @@ class Engine:
                 top_k=self.top_k, top_p=self.top_p)
             return n_acc, emit, new_pool
 
-        sm = jax.shard_map(
-            fn, mesh=self.mesh,
-            in_specs=(self._pspec, self._cspec, P(), P(), P(), P(), P()),
-            out_specs=(P(), P(), self._cspec), check_vma=False)
-        jfn = jax.jit(sm, donate_argnums=(1,))
+        jfn = self._jit_step(fn, f"paged_verify_s{s_v}", 5, 2)
         self._jit_cache[key] = jfn
         return jfn
 
@@ -655,11 +648,7 @@ class Engine:
             return n_acc, emit, new_cache
 
         n_plain = 5 + (1 if paged else 0) + (1 if w > 1 else 0)
-        sm = jax.shard_map(
-            fn, mesh=self.mesh,
-            in_specs=(self._pspec, self._cspec) + (P(),) * n_plain,
-            out_specs=(P(), P(), self._cspec), check_vma=False)
-        jfn = jax.jit(sm, donate_argnums=(1,))
+        jfn = self._jit_step(fn, f"packed_t{t}_w{w}", n_plain, 2)
         self._jit_cache[key] = jfn
         return jfn
 

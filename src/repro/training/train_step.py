@@ -176,12 +176,7 @@ def make_manual_sync_train_step(api: ModelApi, mesh, batch_like,
         n_dp = 1
         for ax in pcfg.dp_axes:
             n_dp *= lax.axis_size(ax)
-        # pre-VMA jax transposes the body_loss pmean by broadcasting the
-        # full cotangent to every model shard (instead of the VMA 1/tp
-        # seed), so every grad leaf comes out exactly tp x too large
-        from repro import compat
-        norm = n_dp if compat.HAS_VMA else n_dp * lax.axis_size(pcfg.tp_axis)
-        grads = jax.tree.map(lambda g: g / norm, grads)
+        grads = jax.tree.map(lambda g: g / n_dp, grads)
         gnorm = _manual_global_norm(grads, pspec, pcfg.tp_axis)
         new_params, new_opt, _ = adamw_update(params, grads, opt, ocfg,
                                               gnorm=gnorm)

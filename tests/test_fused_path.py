@@ -7,8 +7,7 @@ run it, and walks a fallback ladder (ring -> fused composition ->
 vanilla for ragged shards) everywhere else.  This tier pins the MODE —
 not one rung — against ``kernels/ref.ring_ar_rmsnorm_ref`` and the
 unfused vanilla composition, so the numerics hold identically whichever
-rung fires (on jax < 0.5 CPU the interpret gate takes the composition;
-on newer backends the same tests drive the real kernel).
+rung fires (on CPU the Pallas interpreter drives the real kernel).
 
 Also here: the fault-injection half of the tier — a planted
 wrong-chunk-ownership ring schedule must be caught by the numerics pin,
@@ -155,7 +154,8 @@ for dtype, tol in ((jnp.float32, 2e-5), (jnp.bfloat16, 3e-2)):
             f, mesh=mesh, in_specs=(P('model'), P('model')),
             out_specs=(P(None), P('model') if sharded else P(None)),
             check_vma=False))
-        return g(xs, res_in)
+        # fenced: eager dispatch racing an interpreted kernel deadlocks
+        return jax.block_until_ready(g(xs, res_in))
     o_ring, r_ring = run('ring', True)
     o_van, r_van = run('vanilla', False)
     ref_outs, ref_res = ring_ar_rmsnorm_ref(

@@ -61,10 +61,6 @@ def test_flash_attention_matches_oracle(b, sq, sk, kvh, g, dh, causal,
 def test_ring_ar_rmsnorm_multidevice(n, t, d, tmp_path):
     """The paper's fused AllReduce-RMSNorm kernel, validated on n simulated
     devices via the Pallas TPU interpret machinery (subprocess)."""
-    import jax.experimental.pallas.tpu as pltpu
-    if not hasattr(pltpu, "InterpretParams"):
-        pytest.skip("pre-0.5 pallas interpreter cannot emulate the "
-                    "remote-DMA ring kernel (semaphore tracer bug)")
     from conftest import run_distributed
     code = f"""
 import jax, jax.numpy as jnp, numpy as np
@@ -81,7 +77,10 @@ def f(x_shard, res_shard):
                                  n_dev=N, interpret=True)
 g = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P('x'), P('x')),
                           out_specs=(P(None), P('x')), check_vma=False))
-out, new_res = g(xs, res)
+# fence before the reference: the interpreter's callbacks dispatch JAX
+# ops of their own, and eager dispatch racing a still-running interpreted
+# kernel deadlocks it on 8 devices
+out, new_res = jax.block_until_ready(g(xs, res))
 ref_outs, ref_res = ring_ar_rmsnorm_ref(
     [xs[i] for i in range(N)],
     [res.reshape(N, T // N, D)[i] for i in range(N)], w)
