@@ -2,13 +2,16 @@
 
 Everything else in obs/ runs on *virtual* time — the §9 sim roofline
 prices each forward and the trace/metrics record those estimates.  The
-``WallClockProfiler`` adds the missing ground truth: it wraps the
-engine's jitted dispatch functions with ``block_until_ready`` fencing
-(drain pending device work keyed off the cache operand before starting
-the timer, drain the dispatch's own outputs before stopping it) and
-joins each measurement to the SAME ``WeaveAttribution`` record the
-engine emits for that forward — so every sample carries
-(tokens, mode, split, method) next to its wall seconds.
+``WallClockProfiler`` adds the missing ground truth: the engine hands it
+each dispatch's wall time, taken at the same phase boundaries as its
+profiler-clock spans (obs/spans.py) — from the start of ``engine/dispatch``
+(the jitted call) to the end of ``engine/sync`` (the host's ``np.asarray``
+of the outputs, which waits for the program) — and the profiler joins it
+to the SAME ``WeaveAttribution`` record the engine emits for that forward,
+so every sample carries (tokens, mode, split, method) next to its wall
+seconds.  Device work queued on the host just before a dispatch (a slot
+reset, pool fix-ups) is counted in that dispatch: nothing is drained
+first, so timing never changes the run it measures.
 
 Jit compilation is excluded by construction: the first
 ``warmup_per_key`` calls of each compiled shape signature
@@ -28,19 +31,14 @@ Steady samples land in three places:
   * ``steady_samples()``: the raw joined records that
     ``analysis.calibration.fit_calibration`` consumes.
 
-The profiler is pull-only: it never changes what the engine computes
-(the wrapped function is called with identical arguments and its output
-returned untouched), so profiled and unprofiled runs are token- and
-step-identical — tests/test_profiler.py asserts this over the 25-trace
-differential corpus.
+The profiler is pull-only: it never changes what the engine computes, so
+profiled and unprofiled runs are token- and step-identical —
+tests/test_profiler.py asserts this over the 25-trace differential corpus.
 """
 from __future__ import annotations
 
 import dataclasses
-import time
-from typing import Callable, Dict, List, Optional, Tuple
-
-import jax
+from typing import Dict, List, Optional, Tuple
 
 from repro.obs.attribution import WeaveAttribution
 from repro.obs.metrics import MetricsRegistry
@@ -60,33 +58,26 @@ class MeasuredForward:
     tokens_static: int   # b * s — what the split decision saw
     tokens_real: int     # non-pad tokens committed by this forward
     split: Optional[Tuple[int, int]]
-    wall_s: float        # fenced wall-clock seconds for this dispatch
+    wall_s: float        # wall seconds: the call plus the wait for outputs
     est_makespan: float  # §9 roofline prediction under the DEFAULT HW
     warmup: bool         # jit compile / first call on this shape: excluded
 
 
 class WallClockProfiler:
-    """Times engine dispatches; join happens at ``commit``.
+    """Records engine dispatch times joined to their attribution.
 
     Lifecycle (all driven by the engine, see runtime/engine.py):
 
       1. ``attach(registry, trace=..., track=...)`` binds the sinks;
-      2. ``wrap(fn)`` decorates a jitted dispatch function — the wrapper
-         fences, times, and stashes the elapsed seconds as *pending*;
-      3. ``commit(att)`` — called from the engine's single per-dispatch
-         accounting site (``_note_forward``) — pops the pending timing
-         and records the joined ``MeasuredForward``.
-
-    Exactly one wrapped call happens between consecutive commits (the
-    engine runs one model dispatch per ``_note_forward``), so the join
-    needs no correlation ids.
+      2. ``commit(att, wall_s)`` — called from the engine's single
+         per-dispatch accounting site (``_note_forward``), right after the
+         dispatch it times — records the joined ``MeasuredForward``.
     """
 
     def __init__(self, warmup_per_key: int = 1):
         self.warmup_per_key = max(int(warmup_per_key), 0)
         self.samples: List[MeasuredForward] = []
         self._seen: Dict[Tuple, int] = {}
-        self._pending: Optional[float] = None
         self._registry: Optional[MetricsRegistry] = None
         self._trace: Optional[TraceRecorder] = None
         self._track = "engine"
@@ -100,30 +91,11 @@ class WallClockProfiler:
         self._track = track
         return self
 
-    def wrap(self, fn: Callable) -> Callable:
-        """Fenced-timing decorator for a jitted dispatch function.
-
-        ``args[1]`` is the KV-cache pytree by engine convention — fencing
-        on it drains the device queue left by prior dispatches, so the
-        timer measures only this call.  The output is drained too
-        (``block_until_ready``) before the timer stops, then returned
-        unmodified: wrapping never changes what the engine computes.
-        """
-        def timed(*args, **kwargs):
-            if len(args) > 1:
-                jax.block_until_ready(args[1])
-            t0 = time.perf_counter()
-            out = fn(*args, **kwargs)
-            jax.block_until_ready(out)
-            self._pending = time.perf_counter() - t0
-            return out
-        return timed
-
     # -- join ------------------------------------------------------------
-    def commit(self, att: Optional[WeaveAttribution]) -> None:
-        """Join the pending timing to this dispatch's attribution."""
-        wall_s, self._pending = self._pending, None
-        if wall_s is None or att is None:
+    def commit(self, att: Optional[WeaveAttribution],
+               wall_s: float) -> None:
+        """Join one dispatch's wall time to its attribution."""
+        if att is None:
             return
         skey = (att.kind, att.b, att.s)
         seen = self._seen.get(skey, 0)

@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import time
 from typing import Dict, List
 
 import jax
@@ -57,6 +58,7 @@ from repro.models.build import ModelApi, named_shardings
 from repro.obs.attribution import Attributor, WeaveAttribution
 from repro.obs.metrics import MetricsRegistry, percentile as _percentile
 from repro.obs.profiler import WallClockProfiler
+from repro.obs.spans import span
 from repro.obs.trace import TraceRecorder
 from repro.runtime import kv_cache as KC
 from repro.runtime import paging as PG
@@ -309,9 +311,11 @@ class Engine:
         self.obs_track = obs_track
         # measured time (DESIGN.md §13): like obs, the profiler is None by
         # default and every hook is behind an ``is not None`` guard; when
-        # set it only observes (fenced timing around dispatches), so
-        # profiled runs are token- and step-identical to unprofiled ones
+        # set it only observes (each dispatch's time from the same two
+        # stamps as the engine/dispatch and engine/sync spans), so profiled
+        # runs are token- and step-identical to unprofiled ones
         self.profiler = profiler
+        self._dispatch_s = 0.0      # the last dispatch + sync, wall seconds
         if profiler is not None:
             profiler.attach(self.metrics, trace=obs, track=obs_track)
         self._attributor = (Attributor(api.cfg, api.pcfg, api.tp)
@@ -323,6 +327,7 @@ class Engine:
         # (api, mesh, scfg shapes, sampling params) — e.g. the differential
         # harness replaying many short traces — to skip recompilation
         self._jit_cache: Dict = {} if jit_cache is None else jit_cache
+        self._programs_run: set = set()   # step programs dispatched here
         self._pspec = api.specs()
         self._is_ssm = api.cfg.family == "ssm"
         self.paged = bool(scfg.paged)
@@ -414,8 +419,32 @@ class Engine:
         """Per-dispatch PRNG key: one deterministic stream (seeded at
         construction) feeds prefill, decode, and verify sampling alike, so
         stochastic runs are reproducible for a fixed request order."""
-        self._rng_key, k = jax.random.split(self._rng_key)
+        with span("engine/rng"):
+            self._rng_key, k = jax.random.split(self._rng_key)
         return k
+
+    def _inputs(self, *host) -> list:
+        """Device copies of a dispatch's host inputs, then its PRNG key."""
+        return [jnp.asarray(a) for a in host] + [self._next_key()]
+
+    def _dispatch(self, fn, args) -> list:
+        """Run one jitted step on (params, cache, *args) and fetch its
+        outputs to the host; the returned cache replaces ``self.cache``.
+        ``engine/dispatch`` is the call until it returns (a compile shows
+        there, on the program's first dispatch), ``engine/sync`` the host's
+        wait for the outputs.  The profiler's time for the dispatch runs
+        from the first span's start to the second's end."""
+        name = fn.__name__
+        first = name not in self._programs_run
+        self._programs_run.add(name)
+        t0 = time.perf_counter()
+        with span("engine/dispatch", program=name, first=int(first)):
+            *outs, self.cache = fn(self.params, self.cache, *args)
+            args.clear()        # free the device inputs inside the span
+        with span("engine/sync"):
+            outs = [np.asarray(o) for o in outs]
+        self._dispatch_s = time.perf_counter() - t0
+        return outs
 
     # ------------------------------------------------------------------
     # per-site overlap policy (core/policy.py, DESIGN.md §14)
@@ -439,6 +468,7 @@ class Engine:
             self.api, pcfg=dataclasses.replace(self.api.pcfg,
                                                overlap_policy=policy))
         self._jit_cache = {}
+        self._programs_run = set()
         if self._attributor is not None:
             self._attributor = Attributor(self.api.cfg, self.api.pcfg,
                                           self.api.tp)
@@ -671,6 +701,8 @@ class Engine:
                     f"prompt needs {need} blocks but the pool has only "
                     f"{self.scfg.effective_num_blocks} (rid={req.rid})")
         req.arrival_step = self._step_count
+        if req.enqueued_s is None:          # first enqueue, host clock
+            req.enqueued_s = time.perf_counter()
         self.sched.add(req)
         if self.obs is not None:
             self.obs.request_event(req.rid, "queued")
@@ -853,35 +885,52 @@ class Engine:
         return True
 
     def step(self) -> bool:
-        """Run one engine iteration. Returns False when idle."""
-        obs = self.obs
-        if obs is not None:
-            # offline engines self-clock one tick per step; a no-op once
-            # an external owner (server/replica) has synced.  Stamped
-            # BEFORE next_step() so admission events land at step time.
-            obs.auto(float(self._step_count))
-            self._step_forwards = []
-        plan = self.sched.next_step()
-        if plan is None:
-            return False
-        self._step_count += 1
-        self.stats._steps.inc()
+        """Run one engine iteration. Returns False when idle.
 
-        if isinstance(plan, PackedPlan):
-            self._run_packed(plan)
+        Its host phases are profiler-clock spans (obs/spans.py):
+        ``engine/step`` holds ``engine/schedule`` and, for each dispatch,
+        ``engine/prepare`` -> ``engine/dispatch`` -> ``engine/sync`` ->
+        ``engine/commit``."""
+        obs = self.obs
+        with span("engine/step", i=self._step_count) as sp:
             if obs is not None:
-                self._obs_emit_step(packed=True)
+                # offline engines self-clock one tick per step; a no-op
+                # once an external owner (server/replica) has synced.
+                # Stamped BEFORE next_step() so admission events land at
+                # step time.
+                obs.auto(float(self._step_count))
+                self._step_forwards = []
+            with span("engine/schedule"):
+                plan = self.sched.next_step()
+            if plan is None:
+                return False
+            self._step_count += 1
+            self.stats._steps.inc()
+
+            if isinstance(plan, PackedPlan):
+                sp.set_metadata(kind="packed", rows=len(plan.segments),
+                                chunk=plan.total_tokens)
+                self._run_packed(plan)
+                if obs is not None:
+                    self._obs_emit_step(packed=True)
+                return True
+            group, chunk = plan.prefill or ((), 0)
+            dec = plan.decode_slots
+            kind = "verify" if self.spec_gamma else "decode"
+            if not dec or group:
+                kind = "mixed" if dec else "prefill"
+            sp.set_metadata(kind=kind, rows=len(group) + len(dec),
+                            chunk=chunk)
+            if group:
+                self._run_prefill(group, chunk)
+            if dec:
+                if self.spec_gamma:
+                    self._run_verify()
+                else:
+                    self._run_decode()
+            if obs is not None:
+                self._obs_emit_step(packed=False)
             return True
-        if plan.prefill is not None:
-            self._run_prefill(*plan.prefill)
-        if plan.decode_slots:
-            if self.spec_gamma:
-                self._run_verify()
-            else:
-                self._run_decode()
-        if obs is not None:
-            self._obs_emit_step(packed=False)
-        return True
 
     def _obs_emit_step(self, packed: bool) -> None:
         """Emit this iteration's step span plus one nested forward span
@@ -903,13 +952,6 @@ class Engine:
             obs.complete(self.obs_track, f"forward/{a.kind}", t0, d,
                          cat="forward", args=args)
         self._step_forwards = []
-
-    def _prof_wrap(self, jfn):
-        """Fenced wall-clock timing around one dispatch when a
-        ``WallClockProfiler`` is attached (DESIGN.md §13); identity
-        otherwise.  Applied at call sites, not in the jit cache, so a
-        SHARED cache never leaks one engine's profiler into another."""
-        return jfn if self.profiler is None else self.profiler.wrap(jfn)
 
     def _note_forward(self, b: int, s: int, n_real: int, *,
                       decode: bool = False, packed: bool = False,
@@ -946,9 +988,9 @@ class Engine:
             if self.obs is not None:
                 self._step_forwards.append(att)
             if self.profiler is not None:
-                # join the fenced timing _prof_wrap stashed for this very
-                # dispatch to its attribution record (DESIGN.md §13)
-                self.profiler.commit(att)
+                # join this very dispatch's wall time (``_dispatch``) to
+                # its attribution record (DESIGN.md §13)
+                self.profiler.commit(att, self._dispatch_s)
 
     def run(self, max_steps: int = 100000) -> List[Request]:
         while not self.sched.all_done() and max_steps > 0:
@@ -1070,80 +1112,78 @@ class Engine:
 
     # ------------------------------------------------------------------
     def _run_prefill(self, group: List[Request], chunk: int):
-        b_sel = len(group)
-        if self._is_ssm:
-            # ssm chunks must be exact (no pads): shrink to min remainder
-            chunk = min(min(len(r.context_tokens) - r.prefill_pos
-                            for r in group), chunk)
-        tokens = np.zeros((b_sel, chunk), np.int32)
-        positions = np.full((b_sel, chunk), -1, np.int32)
-        offsets = np.zeros(b_sel, np.int32)
-        last_idx = np.zeros(b_sel, np.int32)
-        for i, r in enumerate(group):
-            ctx = r.context_tokens
-            take = min(chunk, len(ctx) - r.prefill_pos)
-            tokens[i, :take] = ctx[r.prefill_pos:r.prefill_pos + take]
-            positions[i, :take] = np.arange(r.prefill_pos,
-                                            r.prefill_pos + take)
-            offsets[i] = r.prefill_pos
-            last_idx[i] = take - 1
-            r.prefill_pos += take
+        with span("engine/prepare"):
+            b_sel = len(group)
+            if self._is_ssm:
+                # ssm chunks must be exact (no pads): shrink to min remainder
+                chunk = min(min(len(r.context_tokens) - r.prefill_pos
+                                for r in group), chunk)
+            tokens = np.zeros((b_sel, chunk), np.int32)
+            positions = np.full((b_sel, chunk), -1, np.int32)
+            offsets = np.zeros(b_sel, np.int32)
+            last_idx = np.zeros(b_sel, np.int32)
+            for i, r in enumerate(group):
+                ctx = r.context_tokens
+                take = min(chunk, len(ctx) - r.prefill_pos)
+                tokens[i, :take] = ctx[r.prefill_pos:r.prefill_pos + take]
+                positions[i, :take] = np.arange(r.prefill_pos,
+                                                r.prefill_pos + take)
+                offsets[i] = r.prefill_pos
+                last_idx[i] = take - 1
+                r.prefill_pos += take
 
-        if self.paged:
-            self._apply_fixups()
-            bt = np.stack([self.block_mgr.table_array(r.rid) for r in group])
-            fn = self._prof_wrap(self._paged_prefill_fn(b_sel, chunk))
-            tok, self.cache = fn(self.params, self.cache,
-                                 jnp.asarray(tokens), jnp.asarray(positions),
-                                 jnp.asarray(bt), jnp.asarray(last_idx),
-                                 self._next_key())
-        else:
-            slot_ids = np.array([r.slot for r in group], np.int32)
-            fn = self._prof_wrap(self._prefill_fn(b_sel, chunk))
-            tok, self.cache = fn(self.params, self.cache,
-                                 jnp.asarray(tokens), jnp.asarray(positions),
-                                 jnp.asarray(slot_ids), jnp.asarray(offsets),
-                                 jnp.asarray(last_idx), self._next_key())
-        tok = np.asarray(tok)
-        n_real = int((positions >= 0).sum())
-        self.stats._prefill_tokens.inc(n_real)
-        self._note_forward(b_sel, chunk, n_real, kind="prefill")
-        for i, r in enumerate(group):
-            self._commit_prefill(r, int(tok[i]))
+            if self.paged:
+                self._apply_fixups()
+                bt = np.stack([self.block_mgr.table_array(r.rid)
+                               for r in group])
+                fn = self._paged_prefill_fn(b_sel, chunk)
+                args = self._inputs(tokens, positions, bt, last_idx)
+            else:
+                slot_ids = np.array([r.slot for r in group], np.int32)
+                fn = self._prefill_fn(b_sel, chunk)
+                args = self._inputs(tokens, positions, slot_ids, offsets,
+                                    last_idx)
+        tok, = self._dispatch(fn, args)
+        with span("engine/commit"):
+            n_real = int((positions >= 0).sum())
+            self.stats._prefill_tokens.inc(n_real)
+            self._note_forward(b_sel, chunk, n_real, kind="prefill")
+            for i, r in enumerate(group):
+                self._commit_prefill(r, int(tok[i]))
 
     def _run_decode(self):
-        if self.paged:
-            reqs = self._ensure_decode_blocks()
-            if not reqs:
-                return
-            self._apply_fixups()
-        else:
-            reqs = [r for r in self.sched.active
-                    if r is not None and r.state == State.DECODE]
-        bmax = self.scfg.max_batch
-        tokens = np.zeros((bmax, 1), np.int32)
-        positions = np.full((bmax, 1), -1, np.int32)
-        for r in reqs:
-            tokens[r.slot, 0] = r.output[-1]
-            positions[r.slot, 0] = r.length - 1
-
-        if self.paged:
-            bt = np.full((bmax, self.scfg.max_blocks_per_req), -1, np.int32)
+        with span("engine/prepare"):
+            if self.paged:
+                reqs = self._ensure_decode_blocks()
+                if not reqs:
+                    return
+                self._apply_fixups()
+            else:
+                reqs = [r for r in self.sched.active
+                        if r is not None and r.state == State.DECODE]
+            bmax = self.scfg.max_batch
+            tokens = np.zeros((bmax, 1), np.int32)
+            positions = np.full((bmax, 1), -1, np.int32)
             for r in reqs:
-                bt[r.slot] = self.block_mgr.table_array(r.rid)
-            fn = self._prof_wrap(self._paged_decode_fn())
-            tok, self.cache = fn(self.params, self.cache,
-                                 jnp.asarray(tokens), jnp.asarray(positions),
-                                 jnp.asarray(bt), self._next_key())
-        else:
-            fn = self._prof_wrap(self._decode_fn())
-            tok, self.cache = fn(self.params, self.cache,
-                                 jnp.asarray(tokens), jnp.asarray(positions),
-                                 self._next_key())
-        tok = np.asarray(tok)
-        self._note_forward(bmax, 1, len(reqs), decode=True, kind="decode")
-        for r in list(reqs):
-            self._commit_decode(r, int(tok[r.slot]))
+                tokens[r.slot, 0] = r.output[-1]
+                positions[r.slot, 0] = r.length - 1
+
+            if self.paged:
+                bt = np.full((bmax, self.scfg.max_blocks_per_req), -1,
+                             np.int32)
+                for r in reqs:
+                    bt[r.slot] = self.block_mgr.table_array(r.rid)
+                fn = self._paged_decode_fn()
+                args = self._inputs(tokens, positions, bt)
+            else:
+                fn = self._decode_fn()
+                args = self._inputs(tokens, positions)
+        tok, = self._dispatch(fn, args)
+        with span("engine/commit"):
+            self._note_forward(bmax, 1, len(reqs), decode=True,
+                               kind="decode")
+            for r in list(reqs):
+                self._commit_decode(r, int(tok[r.slot]))
 
     # ------------------------------------------------------------------
     # speculative decoding (runtime/spec.py, DESIGN.md §8)
@@ -1182,65 +1222,57 @@ class Engine:
         longest accepted prefix + 1 corrected/bonus token, and roll back
         the rejected suffix (paged: block-table truncation)."""
         gamma = self.spec_gamma
-        if self.paged:
-            reqs = self._ensure_decode_blocks()   # input cell is mandatory
+        with span("engine/prepare"):
+            if self.paged:
+                reqs = self._ensure_decode_blocks()  # input cell: mandatory
+            else:
+                reqs = [r for r in self.sched.active
+                        if r is not None and r.state == State.DECODE]
             if not reqs:
                 return
-        else:
-            reqs = [r for r in self.sched.active
-                    if r is not None and r.state == State.DECODE]
-            if not reqs:
-                return
-
-        capped = self._capped_drafts(reqs)
-        if not any(capped.values()):
+            capped = self._capped_drafts(reqs)
+            drafted = any(capped.values())
+            if drafted:
+                if self.paged:
+                    self._apply_fixups()
+                bmax = self.scfg.max_batch
+                s_v = gamma + 1
+                tokens = np.zeros((bmax, s_v), np.int32)
+                positions = np.full((bmax, s_v), -1, np.int32)
+                draft = np.full((bmax, gamma), -1, np.int32)
+                for r in reqs:
+                    prop = capped[r.rid]
+                    tokens[r.slot, 0] = r.output[-1]
+                    positions[r.slot, 0] = r.length - 1
+                    for j, d in enumerate(prop):
+                        tokens[r.slot, 1 + j] = d
+                        positions[r.slot, 1 + j] = r.length + j
+                        draft[r.slot, j] = d
+                if self.paged:
+                    bt = np.full((bmax, self.scfg.max_blocks_per_req), -1,
+                                 np.int32)
+                    for r in reqs:
+                        bt[r.slot] = self.block_mgr.table_array(r.rid)
+                    fn = self._paged_verify_fn(s_v)
+                    args = self._inputs(tokens, positions, bt, draft)
+                else:
+                    fn = self._verify_fn(s_v)
+                    args = self._inputs(tokens, positions, draft)
+        if not drafted:
             # nothing drafted anywhere: a gamma+1-wide verify would pay
             # (gamma+1)x decode compute to commit one token per request —
             # take the plain single-token decode step instead
             self._run_decode()
             return
-        if self.paged:
-            self._apply_fixups()
-
-        bmax = self.scfg.max_batch
-        s_v = gamma + 1
-        tokens = np.zeros((bmax, s_v), np.int32)
-        positions = np.full((bmax, s_v), -1, np.int32)
-        draft = np.full((bmax, gamma), -1, np.int32)
-        for r in reqs:
-            prop = capped[r.rid]
-            tokens[r.slot, 0] = r.output[-1]
-            positions[r.slot, 0] = r.length - 1
-            for j, d in enumerate(prop):
-                tokens[r.slot, 1 + j] = d
-                positions[r.slot, 1 + j] = r.length + j
-                draft[r.slot, j] = d
-
-        rng = self._next_key()
-        if self.paged:
-            bt = np.full((bmax, self.scfg.max_blocks_per_req), -1, np.int32)
-            for r in reqs:
-                bt[r.slot] = self.block_mgr.table_array(r.rid)
-            fn = self._prof_wrap(self._paged_verify_fn(s_v))
-            n_acc, emit, self.cache = fn(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(positions), jnp.asarray(bt), jnp.asarray(draft),
-                rng)
-        else:
-            fn = self._prof_wrap(self._verify_fn(s_v))
-            n_acc, emit, self.cache = fn(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(positions), jnp.asarray(draft), rng)
-        n_acc = np.asarray(n_acc)
-        emit = np.asarray(emit)
-        self._note_forward(bmax, s_v,
-                           sum(1 + len(capped[r.rid]) for r in reqs),
-                           decode=True, kind="verify")
-
-        self.stats.spec._verify_steps.inc()
-        for r in list(reqs):
-            self._commit_verify(r, capped[r.rid], int(n_acc[r.slot]),
-                                int(emit[r.slot]))
+        n_acc, emit = self._dispatch(fn, args)
+        with span("engine/commit"):
+            self._note_forward(bmax, s_v,
+                               sum(1 + len(capped[r.rid]) for r in reqs),
+                               decode=True, kind="verify")
+            self.stats.spec._verify_steps.inc()
+            for r in list(reqs):
+                self._commit_verify(r, capped[r.rid], int(n_acc[r.slot]),
+                                    int(emit[r.slot]))
 
     # ------------------------------------------------------------------
     # packed hybrid batching (DESIGN.md §6)
@@ -1252,102 +1284,99 @@ class Engine:
         recompilation bucket) and dispatched through
         ``ModelApi.packed_step``; unified sampling/verification then
         commits every segment kind from the same (n_acc, emit) pair."""
-        gamma = self.spec_gamma
-        w = gamma + 1 if gamma else 1
-        if self.paged:
-            # grow/COW the decode write cells first; this can preempt or
-            # ceiling-finish DECODE requests, so re-filter the plan
-            self._ensure_decode_blocks()
-        segs = [s for s in plan.segments
-                if s.req.state == (State.PREFILL if s.kind == "prefill"
-                                   else State.DECODE)]
-        if not segs:
-            return
-
-        props: Dict[int, List[int]] = {}
-        if gamma:
-            vreqs = [s.req for s in segs if s.kind == "verify"]
-            if vreqs:
-                props = self._capped_drafts(vreqs)
-        if self.paged:
-            self._apply_fixups()
-
-        def seg_len(s):
-            if s.kind == "prefill":
-                return s.n_tokens
-            if s.kind == "verify":
-                return 1 + len(props.get(s.req.rid, []))
-            return 1
-
-        t_real = sum(seg_len(s) for s in segs)
-        pad_mult = math.lcm(self.scfg.prefill_bucket, self.api.tp)
-        t = pad_to_multiple(t_real, pad_mult)
-        bmax = self.scfg.max_batch
-        tokens = np.zeros((1, t), np.int32)
-        positions = np.full((1, t), -1, np.int32)
-        seg_slots = np.full(t, -1, np.int32)
-        sample_idx = np.full((bmax, w), -1, np.int32)
-        draft = np.full((bmax, gamma), -1, np.int32) if gamma else None
-        bt = (np.full((bmax, self.scfg.max_blocks_per_req), -1, np.int32)
-              if self.paged else None)
-
-        cur = 0
-        for s in segs:
-            r = s.req
-            m = r.slot
+        with span("engine/prepare"):
+            gamma = self.spec_gamma
+            w = gamma + 1 if gamma else 1
             if self.paged:
-                bt[m] = self.block_mgr.table_array(r.rid)
-            if s.kind == "prefill":
-                ctx = r.context_tokens
-                take = s.n_tokens
-                tokens[0, cur:cur + take] = \
-                    ctx[r.prefill_pos:r.prefill_pos + take]
-                positions[0, cur:cur + take] = np.arange(
-                    r.prefill_pos, r.prefill_pos + take)
-                seg_slots[cur:cur + take] = m
-                sample_idx[m, 0] = cur + take - 1
-                r.prefill_pos += take
-                cur += take
-            else:
-                prop = props.get(r.rid, []) if s.kind == "verify" else []
-                tokens[0, cur] = r.output[-1]
-                positions[0, cur] = r.length - 1
-                seg_slots[cur:cur + 1 + len(prop)] = m
-                sample_idx[m, 0] = cur
-                for j, d in enumerate(prop):
-                    tokens[0, cur + 1 + j] = d
-                    positions[0, cur + 1 + j] = r.length + j
-                    draft[m, j] = d
-                    sample_idx[m, 1 + j] = cur + 1 + j
-                cur += 1 + len(prop)
+                # grow/COW the decode write cells first; this can preempt or
+                # ceiling-finish DECODE requests, so re-filter the plan
+                self._ensure_decode_blocks()
+            segs = [s for s in plan.segments
+                    if s.req.state == (State.PREFILL if s.kind == "prefill"
+                                       else State.DECODE)]
+            if not segs:
+                return
 
-        args = [self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(positions), jnp.asarray(seg_slots),
-                jnp.asarray(sample_idx)]
-        if self.paged:
-            args.append(jnp.asarray(bt))
-        if w > 1:
-            args.append(jnp.asarray(draft))
-        args.append(self._next_key())
-        fn = self._prof_wrap(self._packed_fn(t, w))
-        n_acc, emit, self.cache = fn(*args)
-        n_acc = np.asarray(n_acc)
-        emit = np.asarray(emit)
-        self._note_forward(1, t, t_real, packed=True, kind="packed")
+            props: Dict[int, List[int]] = {}
+            if gamma:
+                vreqs = [s.req for s in segs if s.kind == "verify"]
+                if vreqs:
+                    props = self._capped_drafts(vreqs)
+            if self.paged:
+                self._apply_fixups()
 
-        if any(s.kind == "verify" for s in segs):
-            self.stats.spec._verify_steps.inc()
-        for s in segs:
-            r = s.req
-            m = r.slot
-            if s.kind == "prefill":
-                self.stats._prefill_tokens.inc(s.n_tokens)
-                self._commit_prefill(r, int(emit[m]))
-            elif s.kind == "decode":
-                self._commit_decode(r, int(emit[m]))
-            else:
-                self._commit_verify(r, props.get(r.rid, []),
-                                    int(n_acc[m]), int(emit[m]))
+            def seg_len(s):
+                if s.kind == "prefill":
+                    return s.n_tokens
+                if s.kind == "verify":
+                    return 1 + len(props.get(s.req.rid, []))
+                return 1
+
+            t_real = sum(seg_len(s) for s in segs)
+            pad_mult = math.lcm(self.scfg.prefill_bucket, self.api.tp)
+            t = pad_to_multiple(t_real, pad_mult)
+            bmax = self.scfg.max_batch
+            tokens = np.zeros((1, t), np.int32)
+            positions = np.full((1, t), -1, np.int32)
+            seg_slots = np.full(t, -1, np.int32)
+            sample_idx = np.full((bmax, w), -1, np.int32)
+            draft = np.full((bmax, gamma), -1, np.int32) if gamma else None
+            bt = (np.full((bmax, self.scfg.max_blocks_per_req), -1, np.int32)
+                  if self.paged else None)
+
+            cur = 0
+            for s in segs:
+                r = s.req
+                m = r.slot
+                if self.paged:
+                    bt[m] = self.block_mgr.table_array(r.rid)
+                if s.kind == "prefill":
+                    ctx = r.context_tokens
+                    take = s.n_tokens
+                    tokens[0, cur:cur + take] = \
+                        ctx[r.prefill_pos:r.prefill_pos + take]
+                    positions[0, cur:cur + take] = np.arange(
+                        r.prefill_pos, r.prefill_pos + take)
+                    seg_slots[cur:cur + take] = m
+                    sample_idx[m, 0] = cur + take - 1
+                    r.prefill_pos += take
+                    cur += take
+                else:
+                    prop = props.get(r.rid, []) if s.kind == "verify" else []
+                    tokens[0, cur] = r.output[-1]
+                    positions[0, cur] = r.length - 1
+                    seg_slots[cur:cur + 1 + len(prop)] = m
+                    sample_idx[m, 0] = cur
+                    for j, d in enumerate(prop):
+                        tokens[0, cur + 1 + j] = d
+                        positions[0, cur + 1 + j] = r.length + j
+                        draft[m, j] = d
+                        sample_idx[m, 1 + j] = cur + 1 + j
+                    cur += 1 + len(prop)
+
+            host = [tokens, positions, seg_slots, sample_idx]
+            if self.paged:
+                host.append(bt)
+            if w > 1:
+                host.append(draft)
+            fn = self._packed_fn(t, w)
+            args = self._inputs(*host)
+        n_acc, emit = self._dispatch(fn, args)
+        with span("engine/commit"):
+            self._note_forward(1, t, t_real, packed=True, kind="packed")
+            if any(s.kind == "verify" for s in segs):
+                self.stats.spec._verify_steps.inc()
+            for s in segs:
+                r = s.req
+                m = r.slot
+                if s.kind == "prefill":
+                    self.stats._prefill_tokens.inc(s.n_tokens)
+                    self._commit_prefill(r, int(emit[m]))
+                elif s.kind == "decode":
+                    self._commit_decode(r, int(emit[m]))
+                else:
+                    self._commit_verify(r, props.get(r.rid, []),
+                                        int(n_acc[m]), int(emit[m]))
 
     def _maybe_finish(self, r: Request):
         if len(r.output) >= r.max_new_tokens:

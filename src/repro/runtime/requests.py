@@ -64,6 +64,11 @@ class Request:
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
     finish_reason: str = ""           # "" | "stop" | "cancelled" | "expired"
+    # --- host clock (time.perf_counter, local to this process) ---
+    # first ``Engine.add_request`` and first admission to a slot; kept
+    # apart from the virtual times above and never sent over the wire
+    enqueued_s: Optional[float] = None
+    admitted_s: Optional[float] = None
 
     @property
     def context_tokens(self) -> List[int]:

@@ -44,8 +44,10 @@ it): ``PackedPlan.total_tokens <= chunk_tokens``, which requires
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import List, Optional, Tuple, Union
 
+from repro.obs.spans import span
 from repro.runtime.requests import Request, State
 
 
@@ -203,12 +205,18 @@ class Scheduler:
                     break
                 req.prefill_pos = hit
                 req.prompt_hit_tokens = hit
-            self.waiting.pop(0)
-            req.slot = slot
-            req.state = State.PREFILL
-            self.active[slot] = req
-            if self.on_admit is not None:
-                self.on_admit(req)
+            if req.admitted_s is None:       # first admission, host clock
+                req.admitted_s = time.perf_counter()
+            stats = {"rid": req.rid}
+            if req.enqueued_s is not None:
+                stats["waited_ms"] = (req.admitted_s - req.enqueued_s) * 1e3
+            with span("engine/admit", **stats):
+                self.waiting.pop(0)
+                req.slot = slot
+                req.state = State.PREFILL
+                self.active[slot] = req
+                if self.on_admit is not None:
+                    self.on_admit(req)
 
     # ---- preemption ------------------------------------------------------
     def preempt(self, req: Request):

@@ -240,8 +240,9 @@ def decode_frame(buf: bytes) -> Tuple[str, object]:
 # request / handoff envelopes
 # --------------------------------------------------------------------------
 
-# everything except engine-local placement (``slot``); step counters ride
-# along so latency accounting survives a migration
+# everything except engine-local placement (``slot``) and the host-clock
+# stamps (``enqueued_s``/``admitted_s``, local to one process); step
+# counters ride along so latency accounting survives a migration
 _REQ_SCALARS = ("rid", "max_new_tokens", "prefill_pos", "arrival_step",
                 "first_token_step", "done_step", "preemptions",
                 "prompt_hit_tokens", "migrations", "requeues",
