@@ -298,8 +298,9 @@ def attn_decode(params, x, cache, *, positions, cfg, lay: AttnLayout, theta,
     pmax/psum (flash-decoding across chips).
 
     Without ``seq_axis`` this is exactly the S_v == 1 case of
-    ``attn_verify`` (one slot-scatter implementation, shared epilogue);
-    only the context-parallel branch lives here.
+    ``attn_verify``: ``attn_window`` over the old cache plus the new
+    token, then the one slot scatter (shared epilogue).  Only the
+    context-parallel branch, which scatters before it attends, lives here.
     """
     if seq_axis is None:
         return attn_verify(params, x, cache, positions=positions, cfg=cfg,
@@ -338,30 +339,52 @@ def attn_decode(params, x, cache, *, positions, cfg, lay: AttnLayout, theta,
 
 
 def _decode_attn_math(params, q, k, v, kpos, positions, *, x_dtype, cfg,
-                      lay: AttnLayout, window, seq_axis=None):
+                      lay: AttnLayout, window, seq_axis=None, window_kv=None):
     """Shared decode/verify epilogue: grouped-QK logits, masked stable
     softmax (optionally flash-decoding combined over a context-parallel
     ``seq_axis``), V accumulate, output projection.
     q: (B, Sq, h_loc, dh) grouped internally (Sq == 1 for plain decode,
-    gamma+1 for the speculative verify window); k/v: (B, C, kvh, dh)."""
+    gamma+1 for the speculative verify window); k/v: (B, C, kvh, dh).
+    ``window_kv``: (k_new, v_new, pos_new), the step's own keys, which
+    join the cache's in the one softmax (``attn_window``); the cache is
+    then read as it was before the step: only its keys below each row's
+    first window position are visible."""
     b, sq = q.shape[0], q.shape[1]
     kvh = k.shape[2]
     g = lay.h_loc // kvh
     qg = q.reshape(b, sq, kvh, g, cfg.head_dim)
-    # bf16 operands + f32 accumulation (MXU-native) — pre-casting the cache
-    # to f32 would round-trip the whole KV through HBM at double width
-    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
+
+    def scores(kk, msk):
+        # bf16 operands + f32 accumulation (MXU-native) — pre-casting the
+        # cache to f32 would round-trip the whole KV through HBM at double
+        # width
+        lg = jnp.einsum("bqhgd,bkhd->bhgqk", qg, kk,
                         preferred_element_type=jnp.float32) \
-        * (cfg.head_dim ** -0.5)
+            * (cfg.head_dim ** -0.5)
+        return jnp.where(msk[:, None, None], lg, NEG_INF)
+
+    def weigh(p, vv):
+        return jnp.einsum("bhgqk,bkhd->bhgqd", p.astype(vv.dtype), vv,
+                          preferred_element_type=jnp.float32)
+
     msk = _mask(positions, kpos, True, window)  # (B, Sq, C)
-    logits = jnp.where(msk[:, None, None], logits, NEG_INF)
+    if window_kv is not None:
+        msk &= kpos[:, None, :] < positions[:, :1, None]
+    logits = scores(k, msk)
     m = jnp.max(logits, axis=-1)
+    if window_kv is not None:
+        k_new, v_new, pos_new = window_kv
+        logits_new = scores(k_new, _mask(positions, pos_new, True, window))
+        m = jnp.maximum(m, jnp.max(logits_new, axis=-1))
     if seq_axis is not None:
         m = lax.pmax(m, seq_axis)
     p = jnp.exp(logits - m[..., None])
     denom = jnp.sum(p, axis=-1)
-    acc = jnp.einsum("bhgqk,bkhd->bhgqd", p.astype(v.dtype), v,
-                     preferred_element_type=jnp.float32)
+    acc = weigh(p, v)
+    if window_kv is not None:
+        p_new = jnp.exp(logits_new - m[..., None])
+        denom = denom + jnp.sum(p_new, axis=-1)
+        acc = acc + weigh(p_new, v_new)
     if seq_axis is not None:
         # flash-decoding combine across context-parallel shards
         denom = lax.psum(denom, seq_axis)
@@ -399,39 +422,70 @@ def attn_decode_paged(params, x, pool_layer, block_tables, *, positions, cfg,
                              mrope_positions=mrope_positions)
 
 
+def attn_window(params, x, cache, *, positions, cfg, lay: AttnLayout,
+                theta, window: int = 0, mrope_positions=None):
+    """Read-only slot decode/verify attention: the old cache plus the window.
+
+    x: (B, S_v, d) — one token (decode) or a causal window of gamma+1
+    tokens (the pending decode input followed by the draft proposal;
+    positions carry -1 for inactive rows and unused draft slots).
+    cache: one layer's {"k": (B, C, kvh, dh), "v": ..., "pos": (B, C)} as
+    it was before this step; it is read, never written.  One softmax runs
+    over [old cache keys ; window keys]:
+
+    * an old-cache key is visible iff 0 <= kpos < row_start (the row's
+      first window position, ``positions[:, 0]``) and it passes the
+      window mask — this hides what a scatter of the window would have
+      overwritten: the ring slot's ``pos - C`` (C == window on a ring, so
+      the window mask drops it), stale keys of rejected drafts at
+      ``>= row_start``, and everything of an inactive row (row_start -1);
+    * a window key is visible causally (its padding, position -1, never).
+
+    Returns (partial_out (B, S_v, d) — pre-psum over TP, (k_new, v_new,
+    pos_new)): the window's KV, for the caller to write at slot
+    ``pos % C`` (``attn_verify`` per layer; the layer scan once for every
+    layer, ``runtime/kv_cache.insert_window``).
+    """
+    q, k_new, v_new = _project_qkv(params, x, cfg, lay, positions=positions,
+                                   theta=theta,
+                                   mrope_positions=mrope_positions)
+    pos_new = positions.astype(jnp.int32)
+    partial = _decode_attn_math(params, q, cache["k"], cache["v"],
+                                cache["pos"], positions, x_dtype=x.dtype,
+                                cfg=cfg, lay=lay, window=window,
+                                window_kv=(k_new, v_new, pos_new))
+    return partial, (k_new, v_new, pos_new)
+
+
 def attn_verify(params, x, cache, *, positions, cfg, lay: AttnLayout, theta,
                 window: int = 0, mrope_positions=None):
-    """Multi-token speculative-verify decode against the slot KV cache.
+    """Multi-token speculative-verify decode against one layer's slot cache.
 
-    x: (B, S_v, d) — a causal window of gamma+1 tokens per request (the
-    pending decode input followed by the draft proposal; positions carry
-    -1 for inactive rows and unused draft slots, whose writes are dropped).
-    All S_v tokens are scattered into the cache FIRST, then attention runs
-    with the causal mask restricting each query to its own prefix — so the
-    epilogue is shared verbatim with ``attn_decode`` (the S_v == 1 case).
+    ``attn_window`` over the old cache plus the gamma+1 window (x:
+    (B, S_v, d), positions -1 for inactive rows and unused draft slots),
+    then the window's KV scatters into the layer's cache at slot
+    ``pos % C`` (-1 writes are dropped).  Returns (partial_out, updated
+    layer cache); the epilogue is shared verbatim with ``attn_decode``
+    (the S_v == 1 case).
 
-    Multi-token windows (S_v > 1) require full-attention layers: a
-    sliding-window ring buffer (C == window) would let a later window
-    write evict a key an earlier query in the same window still needs.
-    The engine rejects spec decoding on the legacy backend for windowed
+    Multi-token windows (S_v > 1) require full-attention layers: on a
+    sliding-window ring buffer (C == window) the window's scatter evicts
+    keys that a later query still needs once drafts are rejected.  The
+    engine rejects spec decoding on the legacy backend for windowed
     models; the paged backend stores full-length KV and enforces windows
     by mask, so it is unaffected.  (S_v == 1 — plain ``attn_decode``
     delegating here — is safe for any layer kind: one write, one query.)
     """
-    b, s, _ = x.shape
-    q, k_new, v_new = _project_qkv(params, x, cfg, lay, positions=positions,
-                                   theta=theta,
-                                   mrope_positions=mrope_positions)
+    partial, (k_new, v_new, pos) = attn_window(
+        params, x, cache, positions=positions, cfg=cfg, lay=lay,
+        theta=theta, window=window, mrope_positions=mrope_positions)
+    b = x.shape[0]
     c = cache["k"].shape[1]
-    slot = jnp.where(positions >= 0, positions % c, c).astype(jnp.int32)
+    slot = jnp.where(pos >= 0, pos % c, c)
     bidx = jnp.arange(b)[:, None]
     k_c = cache["k"].at[bidx, slot].set(k_new, mode="drop")
     v_c = cache["v"].at[bidx, slot].set(v_new, mode="drop")
-    p_c = cache["pos"].at[bidx, slot].set(positions.astype(jnp.int32),
-                                          mode="drop")
-    partial = _decode_attn_math(params, q, k_c, v_c, p_c, positions,
-                                x_dtype=x.dtype, cfg=cfg, lay=lay,
-                                window=window)
+    p_c = cache["pos"].at[bidx, slot].set(pos, mode="drop")
     return partial, {"k": k_c, "v": v_c, "pos": p_c}
 
 
@@ -484,8 +538,9 @@ def attn_packed(params, x, cache, *, positions, seg_slots, cfg,
     seg_slots: (T,) int32 cache row owning each token (-1 = padding);
     positions: (1, T) absolute query positions (-1 = padding).
 
-    All T tokens scatter into their owning rows FIRST (the same
-    scatter-then-attend discipline as ``attn_verify``), then every token
+    All T tokens scatter into their owning rows FIRST (scatter-then-
+    attend; the slot decode/verify paths attend read-only instead,
+    ``attn_window``), then every token
     attends its own row's full cache view with the causal mask ordering
     queries against both pre-existing context and same-step keys — so a
     segment's later tokens see its earlier ones, and tokens never see

@@ -48,6 +48,7 @@ from repro.layers import attention as A
 from repro.layers import embedding as E
 from repro.layers import mlp as M
 from repro.layers import moe as X
+from repro.runtime import kv_cache as KC
 
 
 # --------------------------------------------------------------------------
@@ -161,10 +162,13 @@ def param_specs(cfg: ModelConfig, pcfg: ParallelConfig):
 
 def _layer_split(lp, h, res, *, positions, mrope_positions, kind: LayerKind,
                  cfg, pcfg, ctx: CommCtx, lay, kv_prefix, cache_layer,
-                 decode: bool, block_tables=None, packed_slots=None):
+                 decode: bool, block_tables=None, packed_slots=None,
+                 window_kv: bool = False):
     """One transformer layer on one token-split.
 
-    Returns (h_next, res, new_kv or new_cache_layer, aux).
+    Returns (h_next, res, new_kv or new_cache_layer, aux).  ``window_kv``
+    (slot decode/verify under the layer scan): the cache layer is only
+    read, and new_kv is the window's (k, v, pos).
     """
     aux = jnp.zeros((), jnp.float32)
     if packed_slots is not None:
@@ -191,6 +195,14 @@ def _layer_split(lp, h, res, *, positions, mrope_positions, kind: LayerKind,
         a_part, kv_out = paged_attn(
             lp["attn"], h, cache_layer, block_tables, positions=positions,
             cfg=cfg, lay=lay, theta=kind.theta, window=kind.window,
+            mrope_positions=mrope_positions)
+    elif decode and window_kv:
+        # stacked slot cache under the layer scan: attend the old cache
+        # plus the window; ``forward`` writes every layer's window once,
+        # after the scan, so the scan never copies or restacks the cache
+        a_part, kv_out = A.attn_window(
+            lp["attn"], h, cache_layer, positions=positions, cfg=cfg,
+            lay=lay, theta=kind.theta, window=kind.window,
             mrope_positions=mrope_positions)
     elif decode and h.shape[1] > 1:
         # legacy-slot speculative verify window (no seq_axis: the verify
@@ -236,11 +248,12 @@ def _layer_split(lp, h, res, *, positions, mrope_positions, kind: LayerKind,
 
 
 def _weave_layer(lp, state, cache_layer, *, kind, cfg, pcfg, ctx, lay,
-                 decode: bool, block_tables=None):
+                 decode: bool, block_tables=None, window_kv: bool = False):
     """Run one layer over one or two splits in paper-Fig.8 order.
 
     state: dict with lists h[i], res[i], positions[i], mrope[i].
-    Returns (state, kv_out or new_cache_layer, aux).
+    Returns (state, kv_out or new_cache_layer, aux); with ``window_kv``
+    the decode kv_out is the window's (k, v, pos), batch splits joined.
     """
     n = len(state["h"])
     kv_outs, auxes = [], []
@@ -290,7 +303,7 @@ def _weave_layer(lp, state, cache_layer, *, kind, cfg, pcfg, ctx, lay,
                 positions=state["positions"][i],
                 mrope_positions=state["mrope"][i], kind=kind, cfg=cfg,
                 pcfg=pcfg, ctx=ctx, lay=lay, kv_prefix=None, cache_layer=cl,
-                decode=True)
+                decode=True, window_kv=window_kv)
             new_h[i], new_res[i] = h, res
             kv_outs.append(kv)
             auxes.append(aux)
@@ -519,7 +532,10 @@ def forward(params, tokens, *, cfg: ModelConfig, pcfg: ParallelConfig,
         chunk's new kv is returned for the engine to insert.
     decode: cache required; S == 1, or S == gamma+1 for the speculative
         verify window (multi-token causal decode attention); returns the
-        updated cache.
+        updated cache.  On a stacked slot cache under the layer scan the
+        layers attend the cache read-only and return only the window's KV,
+        which is written into the cache once, after the scan
+        (``KC.insert_window``).
     block_tables: (B, max_blocks) int32 — switches decode to the paged
         block-pool cache layout (runtime/paging.py); prefill is unaffected
         (the engine pre-gathers the paged prefix into rectangular rows).
@@ -597,13 +613,20 @@ def forward(params, tokens, *, cfg: ModelConfig, pcfg: ParallelConfig,
 
     if scan_mode:
         kind = kinds[0]
+        # slot decode/verify (context-parallel decode excepted: it scatters
+        # into its shard of the cache before it attends)
+        window_kv = (decode and cache is not None and block_tables is None
+                     and not packed
+                     and not (s_total == 1 and pcfg.seq_shard_kv
+                              and kind.window == 0))
 
         def body(carry, xs):
             st, aux = carry
             lp, cache_layer = xs
             st, kv_new, aux_l = _weave_layer(
                 lp, st, cache_layer, kind=kind, cfg=cfg, pcfg=pcfg, ctx=ctx,
-                lay=lay, decode=decode, block_tables=block_tables)
+                lay=lay, decode=decode, block_tables=block_tables,
+                window_kv=window_kv)
             ys = kv_new if (return_kv or decode) else None
             return (st, aux + aux_l), ys
 
@@ -618,6 +641,8 @@ def forward(params, tokens, *, cfg: ModelConfig, pcfg: ParallelConfig,
             bodyfn, scan_xs = body, (params["layers"], cache)
         (state, aux_total), kv_all = lax.scan(
             bodyfn, (state, aux_total), scan_xs)
+        if window_kv:
+            kv_all = KC.insert_window(cache, kv_all)
     else:
         kv_list = []
         for i, kind in enumerate(kinds):

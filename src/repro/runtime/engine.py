@@ -27,8 +27,9 @@ Speculative decoding (``SchedulerConfig.spec_gamma > 0``, runtime/spec.py):
 decode iterations become gamma+1-token verify batches — a pluggable draft
 proposes, ONE multi-token forward scores the window, rejection sampling
 commits the longest accepted prefix + 1 token, and rejected KV is rolled
-back by block-table truncation (paged) or left to the
-overwrite-before-query invariant (legacy slots) — DESIGN.md §8.  Greedy
+back by block-table truncation (paged) or hidden by the legacy slots'
+visibility rule (old-cache keys only below each row's first window
+position) — DESIGN.md §8.  Greedy
 spec output is token-identical to plain greedy decoding.
 
 Packed hybrid batching (``SchedulerConfig.packed``, DESIGN.md §6): the
@@ -1092,7 +1093,8 @@ class Engine:
                        emit: int):
         """Commit the longest accepted draft prefix + the corrected/bonus
         token and roll back rejected KV (paged: block-table truncation;
-        legacy slots need none by the overwrite-before-query invariant)."""
+        legacy slots need none: a step sees old-cache keys only below its
+        window's first position, DESIGN.md §8)."""
         n = min(n_acc, len(prop))
         base_len = r.length          # L: window wrote L-1 .. L-1+|prop|
         r.output.extend(prop[:n] + [emit])

@@ -54,6 +54,33 @@ def insert_chunk(cache, kv_chunk, offsets, slot_ids=None):
     return cache
 
 
+def insert_window(cache, kv_window):
+    """Write a decode/verify window's KV into the stacked cache, every
+    layer in one scatter (the layer scan attends read-only,
+    ``layers/attention.attn_window``, and returns only the window).
+
+    kv_window: (k (L,B,S_v,h,dh), v, pos (L,B,S_v)); each entry goes to
+    slot ``pos % C`` of its row, and pos -1 (inactive rows, unused draft
+    slots) is dropped.  Every axis but head_dim is indexed, so the
+    scatter writes (head_dim,) rows into the cache in place whatever its
+    device layout: the TPU keeps (L, B, C, h, dh) with h outside C, and a
+    scatter whose update windows span h (``[:, rows, slots]``, as
+    ``insert_chunk`` has it) is done on a transposed copy of the cache.
+    """
+    k, v, pos = kv_window
+    n_l, b, _ = pos.shape
+    h = cache["k"].shape[3]
+    c = cache["k"].shape[2]
+    slots = jnp.where(pos >= 0, pos % c, c)              # OOB -> dropped
+    lidx = jnp.arange(n_l)[:, None, None]
+    bidx = jnp.arange(b)[None, :, None]
+    hidx = jnp.arange(h)[None, None, None, :]
+    at_kv = (lidx[..., None], bidx[..., None], slots[..., None], hidx)
+    return {"k": cache["k"].at[at_kv].set(k, mode="drop"),
+            "v": cache["v"].at[at_kv].set(v, mode="drop"),
+            "pos": cache["pos"].at[lidx, bidx, slots].set(pos, mode="drop")}
+
+
 def _insert_layer(layer, kv, offsets, slot_ids):
     k, v, pos = kv
     b_sel, s = pos.shape

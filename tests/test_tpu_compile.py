@@ -107,7 +107,9 @@ def test_engine_decode_step_compiles_full_width(topo):
     """The engine's own jitted decode step for qwen1.5-4b at full width
     and depth (40 layers, scanned, so compile time does not grow with
     depth) over the chip smoke's slot cache (4 x 2048): params, cache and
-    temporaries fit one 16 GB chip."""
+    temporaries fit one 16 GB chip, and the step updates the donated
+    cache in place: its temporaries hold no copy of the cache, nor of one
+    layer's slice of it."""
     from repro.configs import get_config
     from repro.configs.base import ParallelConfig
     from repro.models.build import build_model, named_shardings
@@ -144,3 +146,8 @@ def test_engine_decode_step_compiles_full_width(topo):
              + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     assert ma.argument_size_in_bytes > 11e9     # the whole model + cache
     assert total < V5E_HBM, total
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(cache))
+    layer_kv = cache_bytes / api.cfg.num_layers / 2    # one layer's K
+    assert ma.temp_size_in_bytes < layer_kv, (ma.temp_size_in_bytes,
+                                               layer_kv)
