@@ -495,19 +495,28 @@ def weave_decision(b: int, s: int, *, tp: int, pcfg: ParallelConfig,
                                family=family).weave
 
 
-def _comm_ctx(pcfg: ParallelConfig, cfg: ModelConfig, t_local: int,
-              tp: int, *, mode: Optional[str] = None,
-              budget: float = 1.0) -> CommCtx:
-    """Pick the effective comm mode: the token-sharded (fused/reordered/
-    ring) layouts need t_local divisible by tp; otherwise fall back to
-    vanilla (the paper's fallback for small decode batches).  ``mode``
-    overrides ``pcfg.comm_mode`` when the overlap plan forces one
-    ("ring" = dispatch the real fused kernel, DESIGN.md §14); ``budget``
-    is the plan's comm resource grant, sizing the ring kernel's lanes."""
+def comm_mode_for(pcfg: ParallelConfig, t_local: int, tp: int,
+                  mode: Optional[str] = None) -> str:
+    """The comm mode a forward over ``t_local`` tokens runs at every
+    ``comm_norm`` site: ``mode`` (the overlap plan's, "ring" = the real
+    fused kernel, DESIGN.md §14) or ``pcfg.comm_mode``, except that the
+    token-sharded (fused/reordered/ring) layouts need t_local divisible by
+    tp and fall back to vanilla otherwise (the paper's fallback for small
+    decode batches)."""
     mode = mode or pcfg.comm_mode
     if (mode in ("fused", "reordered", "ring")
             and (t_local % tp != 0 or t_local < tp)):
-        mode = "vanilla"
+        return "vanilla"
+    return mode
+
+
+def _comm_ctx(pcfg: ParallelConfig, cfg: ModelConfig, t_local: int,
+              tp: int, *, mode: Optional[str] = None,
+              budget: float = 1.0) -> CommCtx:
+    """The forward's ``CommCtx``, in the mode ``comm_mode_for`` picks;
+    ``budget`` is the plan's comm resource grant, sizing the ring kernel's
+    lanes."""
+    mode = comm_mode_for(pcfg, t_local, tp, mode)
     return CommCtx(tp_axis=pcfg.tp_axis, dp_axes=pcfg.dp_axes, mode=mode,
                    eps=cfg.norm_eps, use_pallas=pcfg.use_pallas_norm,
                    bf16_wire=pcfg.bf16_wire, comm_budget=budget)

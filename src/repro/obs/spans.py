@@ -16,9 +16,11 @@ priced by the sim.  The engine's spans (all named ``engine/...``):
   ``waited_ms``, for each request admitted) and, for each dispatch:
 * ``engine/prepare``: numpy inputs, block tables and device puts, with
   ``engine/rng`` around the sampling-key split;
-* ``engine/dispatch`` (stats ``program``, the jitted step's name, and
-  ``first``, 1 on a program's first dispatch in the engine): the call until
-  it returns;
+* ``engine/dispatch`` (stats ``program``, the jitted step's name;
+  ``first``, 1 on a program's first dispatch in the engine; ``weave``, the
+  token parts the program splits into, 1 or 2; ``comm``, the mode its
+  ``comm_norm`` sites run: ``fused``, ``vanilla``, ``ring``, ...): the call
+  until it returns;
 * ``engine/sync``: the host waiting for the program's outputs;
 * ``engine/commit``: counters and the per-request commits.
 """

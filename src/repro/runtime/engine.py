@@ -46,7 +46,7 @@ from __future__ import annotations
 import dataclasses
 import math
 import time
-from typing import Dict, List
+from typing import Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -428,18 +428,21 @@ class Engine:
         """Device copies of a dispatch's host inputs, then its PRNG key."""
         return [jnp.asarray(a) for a in host] + [self._next_key()]
 
-    def _dispatch(self, fn, args) -> list:
+    def _dispatch(self, fn, args, info: TRX.WeaveInfo, comm: str) -> list:
         """Run one jitted step on (params, cache, *args) and fetch its
         outputs to the host; the returned cache replaces ``self.cache``.
         ``engine/dispatch`` is the call until it returns (a compile shows
         there, on the program's first dispatch), ``engine/sync`` the host's
         wait for the outputs.  The profiler's time for the dispatch runs
-        from the first span's start to the second's end."""
+        from the first span's start to the second's end.  The dispatch span
+        carries the token parts the program runs in (``info``, its weave
+        decision) and its ``comm_norm`` mode (``comm``)."""
         name = fn.__name__
         first = name not in self._programs_run
         self._programs_run.add(name)
         t0 = time.perf_counter()
-        with span("engine/dispatch", program=name, first=int(first)):
+        with span("engine/dispatch", program=name, first=int(first),
+                  weave=2 if info.weave else 1, comm=comm):
             *outs, self.cache = fn(self.params, self.cache, *args)
             args.clear()        # free the device inputs inside the span
         with span("engine/sync"):
@@ -954,24 +957,32 @@ class Engine:
                          cat="forward", args=args)
         self._step_forwards = []
 
-    def _note_forward(self, b: int, s: int, n_real: int, *,
-                      decode: bool = False, packed: bool = False,
-                      kind: str = "prefill"):
-        """Record one model dispatch: its static (b, s) shape decides the
-        weave (host-side mirror of the trace-time split decision), its
-        real token count feeds tokens/forward.  The SAME decision object
-        feeds the counter and (when tracing) the trace attribution record,
-        so trace-derived weave rates match ``EngineStats.weave_rate``
-        exactly (DESIGN.md §12)."""
-        st = self.stats
-        st._forwards.inc()
-        st._forward_tokens.inc(n_real)
-        st._max_forward_tokens.set_max(n_real)
+    def _weave_info(self, b: int, s: int, *, decode: bool = False,
+                    packed: bool = False) -> Tuple[TRX.WeaveInfo, str]:
+        """The weave decision of a dispatch of static shape (b, s) (the
+        host-side mirror of the trace-time split decision) and the mode its
+        ``comm_norm`` sites run (the forward's own rule), taken once before
+        the dispatch and shared by its span and its counters."""
         info = TRX.weave_decision_info(b, s, tp=self.api.tp,
                                        pcfg=self.api.pcfg, decode=decode,
                                        packed=packed,
                                        paged_pool=self.paged and decode,
                                        family=self.api.cfg.family)
+        return info, TRX.comm_mode_for(self.api.pcfg, b * s, self.api.tp,
+                                       info.comm_mode or None)
+
+    def _note_forward(self, info: TRX.WeaveInfo, comm: str, b: int, s: int,
+                      n_real: int, *, kind: str = "prefill"):
+        """Record one model dispatch: its weave decision and comm mode (the
+        ones its ``engine/dispatch`` span carried) feed the counters, its
+        real token count tokens/forward.  The SAME decision
+        object feeds the counter and (when tracing) the trace attribution
+        record, so trace-derived weave rates match ``EngineStats.weave_rate``
+        exactly (DESIGN.md §12)."""
+        st = self.stats
+        st._forwards.inc()
+        st._forward_tokens.inc(n_real)
+        st._max_forward_tokens.set_max(n_real)
         # per-site weave attribution (DESIGN.md §14): which policy site
         # decided, and whether the weave fired there
         site = info.site or kind
@@ -983,6 +994,11 @@ class Engine:
             # a tuned plan routed this site onto the REAL fused ring
             # AllReduce-RMSNorm kernel (method fused / fused-unsplit)
             self.metrics.counter("engine/site_fused", site=site).inc()
+        if comm == "vanilla":
+            # every comm_norm site of the dispatch ran the plain psum: the
+            # configured mode, or the fallback where tp does not divide
+            # the dispatch's tokens
+            self.metrics.counter("engine/comm_vanilla", site=site).inc()
         if self._attributor is not None:
             att = self._attributor.attribute(info, b=b, s=s, n_real=n_real,
                                              kind=kind)
@@ -1145,11 +1161,13 @@ class Engine:
                 fn = self._prefill_fn(b_sel, chunk)
                 args = self._inputs(tokens, positions, slot_ids, offsets,
                                     last_idx)
-        tok, = self._dispatch(fn, args)
+            info, comm = self._weave_info(b_sel, chunk)
+        tok, = self._dispatch(fn, args, info, comm)
         with span("engine/commit"):
             n_real = int((positions >= 0).sum())
             self.stats._prefill_tokens.inc(n_real)
-            self._note_forward(b_sel, chunk, n_real, kind="prefill")
+            self._note_forward(info, comm, b_sel, chunk, n_real,
+                               kind="prefill")
             for i, r in enumerate(group):
                 self._commit_prefill(r, int(tok[i]))
 
@@ -1180,9 +1198,10 @@ class Engine:
             else:
                 fn = self._decode_fn()
                 args = self._inputs(tokens, positions)
-        tok, = self._dispatch(fn, args)
+            info, comm = self._weave_info(bmax, 1, decode=True)
+        tok, = self._dispatch(fn, args, info, comm)
         with span("engine/commit"):
-            self._note_forward(bmax, 1, len(reqs), decode=True,
+            self._note_forward(info, comm, bmax, 1, len(reqs),
                                kind="decode")
             for r in list(reqs):
                 self._commit_decode(r, int(tok[r.slot]))
@@ -1266,11 +1285,12 @@ class Engine:
             # take the plain single-token decode step instead
             self._run_decode()
             return
-        n_acc, emit = self._dispatch(fn, args)
+        info, comm = self._weave_info(bmax, s_v, decode=True)
+        n_acc, emit = self._dispatch(fn, args, info, comm)
         with span("engine/commit"):
-            self._note_forward(bmax, s_v,
+            self._note_forward(info, comm, bmax, s_v,
                                sum(1 + len(capped[r.rid]) for r in reqs),
-                               decode=True, kind="verify")
+                               kind="verify")
             self.stats.spec._verify_steps.inc()
             for r in list(reqs):
                 self._commit_verify(r, capped[r.rid], int(n_acc[r.slot]),
@@ -1363,9 +1383,10 @@ class Engine:
                 host.append(draft)
             fn = self._packed_fn(t, w)
             args = self._inputs(*host)
-        n_acc, emit = self._dispatch(fn, args)
+            info, comm = self._weave_info(1, t, packed=True)
+        n_acc, emit = self._dispatch(fn, args, info, comm)
         with span("engine/commit"):
-            self._note_forward(1, t, t_real, packed=True, kind="packed")
+            self._note_forward(info, comm, 1, t, t_real, kind="packed")
             if any(s.kind == "verify" for s in segs):
                 self.stats.spec._verify_steps.inc()
             for s in segs:

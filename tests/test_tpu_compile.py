@@ -103,26 +103,19 @@ def test_ring_ar_rmsnorm_compiles_on_1x4_mesh(mesh_1x4):
     assert _kernel_count(c) == 1
 
 
-def test_engine_decode_step_compiles_full_width(topo):
-    """The engine's own jitted decode step for qwen1.5-4b at full width
-    and depth (40 layers, scanned, so compile time does not grow with
-    depth) over the chip smoke's slot cache (4 x 2048): params, cache and
-    temporaries fit one 16 GB chip, and the step updates the donated
-    cache in place: its temporaries hold no copy of the cache, nor of one
-    layer's slice of it."""
+def _engine_shell(model, tp, mesh, scfg):
+    """An engine's step-program builder for ``model`` at ``tp`` over
+    ``mesh``, with its parameters and slot cache as shapes: the step
+    programs need the engine's layout, not its device arrays (a described
+    device cannot hold any)."""
     from repro.configs import get_config
     from repro.configs.base import ParallelConfig
     from repro.models.build import build_model, named_shardings
     from repro.runtime.engine import Engine
-    from repro.runtime.scheduler import SchedulerConfig
 
-    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
-    api = build_model(get_config("qwen1.5-4b"),
+    api = build_model(get_config(model),
                       ParallelConfig(comm_mode="fused", tokenweave=True,
-                                     remat=False), tp=1)
-    scfg = SchedulerConfig(max_batch=4, max_len=2048)
-    # _decode_fn needs the engine's layout, not its device arrays
-    # (a described device cannot hold any): an Engine shell without them
+                                     remat=False), tp=tp)
     eng = object.__new__(Engine)
     eng.api, eng.mesh, eng.scfg = api, mesh, scfg
     eng._pspec, eng._cspec = api.specs(), api.cache_specs()
@@ -135,19 +128,76 @@ def test_engine_decode_step_compiles_full_width(topo):
             named_shardings(mesh, specs))
     params = abstract(jax.eval_shape(api.init, jax.random.PRNGKey(0)),
                       api.specs())
-    cache = abstract(jax.eval_shape(lambda: api.init_cache(4, 2048)),
-                     api.cache_specs())
+    cache = abstract(jax.eval_shape(
+        lambda: api.init_cache(scfg.max_batch, scfg.max_len)),
+        api.cache_specs())
+    return eng, params, cache
+
+
+def _bytes_needed(compiled) -> float:
+    ma = compiled.memory_analysis()
+    return (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+
+
+def test_engine_decode_step_compiles_full_width(topo):
+    """The engine's own jitted decode step for qwen1.5-4b at full width
+    and depth (40 layers, scanned, so compile time does not grow with
+    depth) over the chip smoke's slot cache (4 x 2048): params, cache and
+    temporaries fit one 16 GB chip, and the step updates the donated
+    cache in place: its temporaries hold no copy of the cache, nor of one
+    layer's slice of it."""
+    from repro.runtime.scheduler import SchedulerConfig
+
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    eng, params, cache = _engine_shell(
+        "qwen1.5-4b", 1, mesh, SchedulerConfig(max_batch=4, max_len=2048))
+    api = eng.api
     rep = NamedSharding(mesh, P())
     c = eng._decode_fn().lower(
         params, cache, _sds((4, 1), rep, jnp.int32),
         _sds((4, 1), rep, jnp.int32), _sds((2,), rep, jnp.uint32)).compile()
     ma = c.memory_analysis()
-    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
-             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     assert ma.argument_size_in_bytes > 11e9     # the whole model + cache
+    total = _bytes_needed(c)
     assert total < V5E_HBM, total
     cache_bytes = sum(a.size * a.dtype.itemsize
                       for a in jax.tree.leaves(cache))
     layer_kv = cache_bytes / api.cfg.num_layers / 2    # one layer's K
     assert ma.temp_size_in_bytes < layer_kv, (ma.temp_size_in_bytes,
                                                layer_kv)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_b4_s512"])
+def test_qwen3_14b_tp4_step_programs_fit_at_32_slots(program, mesh_1x4):
+    """qwen3-14b at full width and depth on the (1, 4) serving mesh of a
+    v5e 2x2 over the benchmark's slot cache (32 slots x 2048): the decode
+    step, and the prefill program with the most temporaries of those the
+    benchmark's traffic emits (4 rows of 512 tokens: 10.08 GB of weights
+    and cache plus 5.43 GB of temporaries a chip), fit one 16 GB chip.
+    Both run the token-sharded ``fused`` comm_norm: 32 decode rows and
+    2048 prefill tokens divide over the 4 chips, so the programs gather
+    the normed token shards back (the compiler keeps prefill's
+    reduce-scatters and turns decode's small ones into all-reduces)."""
+    from repro.runtime.scheduler import SchedulerConfig
+
+    eng, params, cache = _engine_shell(
+        "qwen3-14b", 4, mesh_1x4,
+        SchedulerConfig(max_batch=32, max_len=2048, chunk_tokens=2048,
+                        prefill_bucket=256))
+    rep = NamedSharding(mesh_1x4, P())
+    key = _sds((2,), rep, jnp.uint32)
+    if program == "decode":
+        ids = _sds((32, 1), rep, jnp.int32)
+        c = eng._decode_fn().lower(params, cache, ids, ids, key).compile()
+    else:
+        ids = _sds((4, 512), rep, jnp.int32)
+        rows = _sds((4,), rep, jnp.int32)
+        c = eng._prefill_fn(4, 512).lower(params, cache, ids, ids, rows,
+                                          rows, rows, key).compile()
+    assert c.memory_analysis().argument_size_in_bytes > 10e9
+    total = _bytes_needed(c)
+    assert total < V5E_HBM, total
+    text = c.as_text()
+    assert "all-gather" in text
+    assert ("reduce-scatter" in text) == (program != "decode")
