@@ -527,7 +527,8 @@ class Engine:
             logits, kv, _ = api.mod.prefill(
                 params, tokens, rows, cfg=api.cfg, pcfg=api.pcfg,
                 positions=positions, last_idx=last_idx)
-            new_cache = KC.insert_chunk(cache, kv, offsets, slot_ids)
+            new_cache = KC.insert_chunk(cache, kv, offsets, slot_ids,
+                                        max_len=self.scfg.max_len)
             tok = sample(logits, vocab_size=api.cfg.vocab_size,
                          tp_axis=api.pcfg.tp_axis,
                          temperature=self.temperature,
@@ -1168,6 +1169,10 @@ class Engine:
             self.stats._prefill_tokens.inc(n_real)
             self._note_forward(info, comm, b_sel, chunk, n_real,
                                kind="prefill")
+            if not (self.paged or self._is_ssm) and "k" in self.cache:
+                # the chunk went into the stacked slot cache in place
+                self.metrics.counter("engine/prefill_write",
+                                     form="inplace").inc()
             for i, r in enumerate(group):
                 self._commit_prefill(r, int(tok[i]))
 
