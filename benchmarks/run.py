@@ -847,219 +847,6 @@ def serve_cluster_wire(quick=False):
     _reg("serve/cluster_wire/requeued", snap, "cluster/requeued")
 
 
-def serve_policy(quick=False):
-    """Per-site overlap policy & tuned plan cache (core/policy.py +
-    analysis/autotune.py, DESIGN.md §14).
-
-    Part 1 — CPU-real: the tiny engine on the same seeded trace under the
-    DEGENERATE global-threshold policy (plan id 0) vs the committed tuned
-    plan cache (``benchmarks/plans/default.json``), on both dispatch
-    schemes.  Emitted tokens are pinned identical across all four runs —
-    the policy only reshapes HOW a forward overlaps, never what it
-    computes — and the trace-derived weave counts must equal the engine
-    counters on every traced run.  Per-site weave rates come from the
-    engine's ``engine/site_weave_rate{site=...}`` gauges; since the plan
-    routes every tiny bucket onto the ring fused kernel (method fused /
-    fused-unsplit), the tuned engine's ``engine/site_fused_rate`` gauges
-    must read 1.0 — on this CPU backend the ring mode gates down the
-    fallback ladder, which is exactly why the tokens stay pinned.
-
-    Part 2 — analytic (sim, 70B/tp8): the load sweep where the tuned
-    plan must beat the degenerate policy — its fused entries dispatch
-    the ring AllReduce-RMSNorm kernel on a half ring-lane grant
-    (budget 0.5 -> 4 lanes, the paper's few-SM fused collective), so the
-    overlapped fraction rises and the makespan drops at EVERY sweep
-    point.  Both asserted strictly.
-
-    Part 3 — analytic (sim, 70B/tp8): the fused-path crossover the
-    paper claims (Fig. 8): the tuned fused configuration must STRICTLY
-    beat both the unsplit fused-collective baseline (fuseonly — no
-    weave) and the naive weave (tokenweave with composed collectives) at
-    every sweep point; the minimum gains are gated in baseline.json."""
-    import os
-
-    from repro.configs.base import ModelConfig, ParallelConfig
-    from repro.core.policy import load_policy
-    from repro.models.build import build_model
-    from repro.runtime.engine import Engine
-    from repro.runtime.requests import sharegpt_like_trace
-    from repro.runtime.scheduler import SchedulerConfig
-
-    plan_path = os.path.join(os.path.dirname(__file__), "plans",
-                             "default.json")
-    cfg = ModelConfig(name="tiny", family="dense", num_layers=2, d_model=64,
-                      num_heads=4, num_kv_heads=2, head_dim=16, d_ff=128,
-                      vocab_size=128, dtype="float32")
-    pcfg = ParallelConfig(tokenweave=True, comm_mode="fused", remat=False,
-                          split_unit=16, tokenweave_min_tokens=32)
-    mesh = jax.make_mesh((1, 1), ("data", "model"),
-                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    api = build_model(cfg, pcfg, tp=1)
-    params = api.init(jax.random.PRNGKey(0))
-    n_req = 8 if quick else 16
-    jit_caches: dict = {}
-
-    def trace():
-        t = sharegpt_like_trace(n_req, vocab=cfg.vocab_size, seed=7,
-                                max_in=56, max_out=8)
-        for r in t:
-            r.max_new_tokens = max(2, min(r.max_new_tokens, 8))
-        return t
-
-    def run(tag, packed, plan=None):
-        rec = _recorder(f"policy:{tag}")
-        eng = Engine(api, mesh, params,
-                     SchedulerConfig(max_batch=4, chunk_tokens=64,
-                                     max_len=256, prefill_bucket=16,
-                                     paged=True, packed=packed,
-                                     plan_path=plan),
-                     jit_cache=jit_caches.setdefault((tag, packed), {}),
-                     obs=rec, obs_track=f"policy/{tag}")
-        for r in trace():
-            eng.add_request(r)
-        done = eng.run()
-        return eng, {r.rid: r.output for r in done}, rec
-
-    t0 = time.perf_counter()
-    eng_d2, ref, rec_d2 = run("threshold", False)
-    eng_dp, got_dp, rec_dp = run("threshold_packed", True)
-    eng_t2, got_t2, rec_t2 = run("tuned", False, plan=plan_path)
-    eng_tp, got_tp, rec_tp = run("tuned_packed", True, plan=plan_path)
-    dt = time.perf_counter() - t0
-    for what, got in (("threshold packed", got_dp), ("tuned", got_t2),
-                      ("tuned packed", got_tp)):
-        assert got == ref, f"serve/policy: {what} changed emitted tokens!"
-    for rec, eng, what in ((rec_d2, eng_d2, "threshold"),
-                           (rec_dp, eng_dp, "threshold packed"),
-                           (rec_t2, eng_t2, "tuned"),
-                           (rec_tp, eng_tp, "tuned packed")):
-        _assert_trace_matches(rec, eng.stats, f"serve/policy {what}")
-
-    snap_d2 = eng_d2.metrics_snapshot()
-    snap_dp = eng_dp.metrics_snapshot()
-    snap_tp = eng_tp.metrics_snapshot()
-    tuned_id = int(snap_tp["engine/plan_id"])
-    assert snap_d2["engine/plan_id"] == 0, \
-        "degenerate engine must report plan id 0"
-    assert tuned_id > 0, "tuned engine did not load the plan cache"
-    # gated: the degenerate plan id is pinned 0; the tuned plan id is
-    # content-derived (changes on every retune), so the GATE is only
-    # that a plan loaded — the actual id is reported in the CSV row
-    _reg("serve/policy/plan_id", snap_d2, "engine/plan_id")
-    _metric("serve/policy/tuned_plan_loaded", 1.0,
-            source="derived:engine/plan_id > 0 (tuned engine)")
-    _reg("serve/policy/weave_rate", snap_dp, "engine/weave_rate")
-    _reg("serve/policy/tuned_weave_rate", snap_tp, "engine/weave_rate")
-    _reg("serve/policy/site_weave_rate_prefill", snap_d2,
-         "engine/site_weave_rate{site=prefill}")
-    _reg("serve/policy/site_weave_rate_decode", snap_d2,
-         "engine/site_weave_rate{site=decode}")
-    _reg("serve/policy/site_weave_rate_packed", snap_dp,
-         "engine/site_weave_rate{site=packed}")
-    _reg("serve/policy/tuned_site_weave_rate_packed", snap_tp,
-         "engine/site_weave_rate{site=packed}")
-    # fused-path routing: every decided site of the tuned engine rides a
-    # fused (ring-mode) plan entry; the threshold engine rides none
-    assert snap_tp["engine/site_fused_rate{site=packed}"] == 1.0, \
-        "tuned plan did not route the packed site onto the fused path"
-    assert snap_dp.get("engine/site_fused_rate{site=packed}", 0.0) == 0.0
-    _reg("serve/policy/tuned_site_fused_rate_packed", snap_tp,
-         "engine/site_fused_rate{site=packed}")
-
-    # ---- part 2: tuned-vs-threshold on the sim load sweep (70B/tp8) ---
-    from repro.configs import get_config
-    from repro.core.splitting import plan_split
-    from repro.obs import MetricsRegistry
-    from repro.sim.overlap_sim import HW, step_attribution
-    big = get_config("llama3.3-70b")
-    unit = ParallelConfig().split_unit_for(8)
-    hw = HW(tile=unit)
-    policy = load_policy(plan_path)
-    sim_mode = {"weave": "tokenweave", "fused": "ringweave",
-                "fused-unsplit": "ring", "none": "vanilla"}
-    toks = [512, 2048, 8192] if quick else [512, 1024, 2048, 4096, 8192]
-    deg_mk = deg_ov = tun_mk = tun_ov = 0.0
-    for n in toks:
-        deg = step_attribution(big, "tokenweave", n, tp=8, hw=hw)
-        plan = policy.plan_for("prefill", n, tp=8, family=big.family)
-        assert plan is not None, f"plan cache misses 70B/tp8 at {n} tokens"
-        tun = step_attribution(
-            big, sim_mode[plan.method], n, tp=8, hw=hw,
-            split=(plan_split(n, unit, plan.split_frac)
-                   if plan.method in ("weave", "fused") else None),
-            comm_budget=None if plan.budget == 1.0 else plan.budget)
-        assert tun["makespan"] < deg["makespan"], (
-            f"tuned plan slower than threshold at {n} tokens: "
-            f"{tun['makespan']:.3e} vs {deg['makespan']:.3e}")
-        assert tun["overlapped"] / tun["makespan"] > \
-            deg["overlapped"] / deg["makespan"], (
-            f"tuned overlap fraction not above threshold at {n} tokens")
-        deg_mk += deg["makespan"]
-        deg_ov += deg["overlapped"]
-        tun_mk += tun["makespan"]
-        tun_ov += tun["overlapped"]
-    deg_frac, tun_frac = deg_ov / deg_mk, tun_ov / tun_mk
-    assert tun_frac > deg_frac, (
-        f"tuned aggregate overlap fraction {tun_frac:.4f} not above the "
-        f"global threshold's {deg_frac:.4f}")
-    # provenance: publish the sim fractions through a registry snapshot
-    # like every other gated metric
-    simreg = MetricsRegistry()
-    simreg.gauge("sim/policy/overlap_frac", policy="threshold").set(deg_frac)
-    simreg.gauge("sim/policy/overlap_frac", policy="tuned").set(tun_frac)
-    snap_sim = simreg.snapshot()
-    _reg("serve/policy/sim_overlap_frac_threshold", snap_sim,
-         "sim/policy/overlap_frac{policy=threshold}")
-    _reg("serve/policy/sim_overlap_frac_tuned", snap_sim,
-         "sim/policy/overlap_frac{policy=tuned}")
-
-    # ---- part 3: fused crossover — ring-fused vs unsplit vs naive weave
-    gain_unsplit = gain_weave = float("inf")
-    for n in toks:
-        plan = policy.plan_for("prefill", n, tp=8, family=big.family)
-        assert plan is not None and plan.method in ("fused",
-                                                    "fused-unsplit"), (
-            f"70B/tp8 plan entry at {n} tokens is {plan and plan.method!r}"
-            f", expected a fused method")
-        fused = step_attribution(
-            big, sim_mode[plan.method], n, tp=8, hw=hw,
-            split=(plan_split(n, unit, plan.split_frac)
-                   if plan.method == "fused" else None),
-            comm_budget=None if plan.budget == 1.0 else plan.budget)
-        unsplit = step_attribution(big, "fuseonly", n, tp=8, hw=hw)
-        naive = step_attribution(big, "tokenweave", n, tp=8, hw=hw)
-        assert fused["makespan"] < unsplit["makespan"], (
-            f"fused not beating unsplit at {n} tokens: "
-            f"{fused['makespan']:.3e} vs {unsplit['makespan']:.3e}")
-        assert fused["makespan"] < naive["makespan"], (
-            f"fused not beating naive weave at {n} tokens: "
-            f"{fused['makespan']:.3e} vs {naive['makespan']:.3e}")
-        gain_unsplit = min(gain_unsplit,
-                           unsplit["makespan"] / fused["makespan"])
-        gain_weave = min(gain_weave, naive["makespan"] / fused["makespan"])
-    simreg.gauge("sim/policy/fused_gain", vs="unsplit").set(gain_unsplit)
-    simreg.gauge("sim/policy/fused_gain", vs="naive_weave").set(gain_weave)
-    snap_sim = simreg.snapshot()
-    _reg("serve/policy/sim_fused_gain_vs_unsplit", snap_sim,
-         "sim/policy/fused_gain{vs=unsplit}")
-    _reg("serve/policy/sim_fused_gain_vs_weave", snap_sim,
-         "sim/policy/fused_gain{vs=naive_weave}")
-
-    steps = eng_dp.stats.steps + eng_tp.stats.steps
-    _row("serve/policy", dt * 1e6 / max(steps, 1),
-         f"plan_id=0 tuned_plan_id={tuned_id} "
-         f"weave_rate={eng_dp.stats.weave_rate:.2f} "
-         f"tuned_weave_rate={eng_tp.stats.weave_rate:.2f} "
-         f"outputs_identical=True")
-    _row("serve/policy/sim_tp8_sweep", tun_mk / len(toks) * 1e6,
-         f"overlap_frac_threshold={deg_frac:.3f} "
-         f"overlap_frac_tuned={tun_frac:.3f} "
-         f"makespan_gain={deg_mk / tun_mk:.3f}x")
-    _row("serve/policy/sim_fused_crossover", tun_mk / len(toks) * 1e6,
-         f"min_gain_vs_unsplit={gain_unsplit:.3f}x "
-         f"min_gain_vs_naive_weave={gain_weave:.3f}x")
-
-
 def fig14_overlap_comparison(quick=False):
     """Paper Fig.14 analogue: TokenWeave vs a TileLink-style GEMM-fused
     overlap (which can only hide comm inside GEMMs and pays split RS/AG)."""
@@ -1200,7 +987,7 @@ def profile_calibration(quick=False, report_path=None):
 FIGS = [fig1_comm_overhead, fig4_fused_kernel, fig9_smart_split,
         fig11_latency, fig12_throughput, fig12_engine_cpu,
         serve_prefix_cache, serve_spec_decode, serve_packed, serve_online,
-        serve_cluster, serve_cluster_wire, serve_policy,
+        serve_cluster, serve_cluster_wire,
         fig14_overlap_comparison,
         fig16_ablation, kernels_micro]
 

@@ -9,10 +9,8 @@
 Summary mode prints, per engine track: step/forward span counts, the
 trace-derived weave rate (weave forwards / forwards, recomputed from the
 per-forward attribution records — the same number `EngineStats.weave_rate`
-reports), the per-forward decision reasons with the OVERLAP-POLICY plan
-ids that made them (plan id 0 = the degenerate global threshold, a
-nonzero id = a tuned plan cache from ``analysis/autotune.py``,
-DESIGN.md §14), and the estimated compute / comm / overlapped
+reports), the per-forward decision reasons of the weave rule
+(DESIGN.md §14), and the estimated compute / comm / overlapped
 virtual-time totals from the §9 sim roofline.  ``--request`` walks one request's
 lifecycle thread event by event (arrival → ... → terminal) including
 every forward step that touched it.  ``--validate`` runs the full schema
@@ -57,7 +55,6 @@ def summarize(doc: dict) -> None:
                                "compute": 0.0, "comm": 0.0,
                                "overlapped": 0.0,
                                "by_reason": defaultdict(int),
-                               "by_plan": defaultdict(int),
                                "by_method": defaultdict(int)})
     requests = defaultdict(list)
     for ev in doc["traceEvents"]:
@@ -73,7 +70,6 @@ def summarize(doc: dict) -> None:
             t["comm"] += a.get("est_comm", 0.0)
             t["overlapped"] += a.get("est_overlapped", 0.0)
             t["by_reason"][a.get("reason", "?")] += 1
-            t["by_plan"][a.get("plan_id", 0)] += 1
             t["by_method"][a.get("method", "?")] += 1
         elif ph == "i" and cat == "request":
             requests[(ev["pid"], ev["tid"])].append(ev["name"])
@@ -86,16 +82,12 @@ def summarize(doc: dict) -> None:
         reasons = ", ".join(f"{k}={v}" for k, v in
                             sorted(t["by_reason"].items()))
         print(f"  decisions: {reasons}")
-        # method=ring/ringweave rows are forwards the plan routed onto the
-        # REAL fused ring AllReduce-RMSNorm kernel (DESIGN.md §14)
+        # method=ring/ringweave rows are forwards priced as the REAL
+        # fused ring AllReduce-RMSNorm kernel
         methods = ", ".join(
             f"{k}{' [fused-kernel]' if k in ('ring', 'ringweave') else ''}"
             f"={v}" for k, v in sorted(t["by_method"].items()))
         print(f"  methods: {methods}")
-        plans = ", ".join(
-            f"{'global-threshold' if pid == 0 else f'plan {pid}'}={v}"
-            for pid, v in sorted(t["by_plan"].items()))
-        print(f"  decided by: {plans}")
         print(f"  est virtual time: compute={t['compute']:.6g} "
               f"comm={t['comm']:.6g} overlapped={t['overlapped']:.6g}")
     n_term = sum(1 for phases in requests.values()
@@ -160,11 +152,10 @@ def show_request(doc: dict, rid: str) -> int:
                      if v is not None}
             print(f"  t={ev['ts'] / 1e6:10.4f}  {ev['name']:<15} {extra}")
     # every forward span whose step committed tokens for this rid is not
-    # tagged per-rid (packed forwards are shared); show the overlap-policy
+    # tagged per-rid (packed forwards are shared); show the weave
     # decision log of all forwards instead, time-interleaved with the
-    # lifecycle — each row names the plan that decided it (plan 0 = the
-    # degenerate global threshold, DESIGN.md §14)
-    print(f"\noverlap-policy decisions while {rid} was live (all tracks):")
+    # lifecycle (DESIGN.md §14)
+    print(f"\nweave decisions while {rid} was live (all tracks):")
     first = min((ev["ts"] for ev in doc["traceEvents"]
                  if (ev.get("pid"), ev.get("tid")) == (pid, tid)
                  and "ts" in ev), default=0.0)
@@ -178,12 +169,9 @@ def show_request(doc: dict, rid: str) -> int:
             continue
         a = ev.get("args", {})
         track = procs.get(ev["pid"], ev["pid"])
-        plan = a.get("plan_id", 0)
         print(f"  t={ev['ts'] / 1e6:10.4f}  {track:<10} {ev['name']:<16} "
               f"weave={str(bool(a.get('weave'))):<5} "
               f"reason={a.get('reason', '?'):<16} tokens={a.get('tokens')} "
-              f"plan={'threshold' if plan == 0 else plan} "
-              f"bucket={a.get('bucket', '?')} "
               f"ovl={a.get('est_overlapped', 0.0):.3g}")
     return 0
 

@@ -206,11 +206,6 @@ class ParallelConfig:
     seq_shard_kv: bool = False             # context-parallel KV over dp axis
     grad_compression: str = "none"         # none | int8
     moe_ep_axis: str = "data"              # a2a axis for ep2d partitioning
-    # per-site overlap policy (core/policy.OverlapPolicy, DESIGN.md §14);
-    # None = degenerate global-threshold policy (token-identical to the
-    # legacy split_decision path). Typed Any to avoid a configs->core
-    # import; policies are frozen/hashable so the config stays hashable.
-    overlap_policy: "object | None" = None
 
     @property
     def axes(self) -> Tuple[str, ...]:

@@ -23,9 +23,10 @@ Modes:
                 XLA collectives)
     ring      : the REAL single-kernel path — kernels/ring_ar_rmsnorm.py
                 does reduce-scatter + fused add/norm + all-gather in ONE
-                Pallas kernel on ``ring_channels(ctx.comm_budget)`` comm
-                lanes (the paper's 2-8 SM multimem kernel, TPU ring
-                analogue). Falls down a ladder to the ``fused``
+                Pallas kernel (the paper's multimem kernel, TPU ring
+                analogue), reached only through
+                ``ParallelConfig.comm_mode="ring"``. Falls down a ladder
+                to the ``fused``
                 composition when the backend can't run it (see
                 ``_ring_supported``); numerics pinned either way by
                 tests/test_fused_path.py.
@@ -37,11 +38,15 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.splitting import ring_channels
 from repro.distributed.context import CommCtx, token_shard_slice
 from repro.kernels.ops import fused_residual_rmsnorm
 from repro.kernels.ring_ar_rmsnorm import ring_fused_ar_rmsnorm
 from repro.layers.norms import residual_rmsnorm_unfused, rms_norm
+
+
+# comm slots the ring kernel cycles through; the kernel clamps them to
+# [1, tp - 1], so every ring dispatch runs min(8, tp - 1)
+RING_CHANNELS = 8
 
 
 def _ring_supported(ctx: CommCtx, reduce_input: bool, weight_post) -> bool:
@@ -83,7 +88,7 @@ def comm_norm(x, residual, weight, *, ctx: CommCtx, reduce_input: bool = True,
             return ring_fused_ar_rmsnorm(
                 x, residual, weight, axis_name=ctx.tp_axis,
                 n_dev=ctx.tp_size(), eps=ctx.eps, interpret=ctx.interpret,
-                channels=max(1, ring_channels(ctx.comm_budget)))
+                channels=RING_CHANNELS)
         mode = "fused"  # rung 2 of the ladder: the composed RS/fused/AG path
 
     # --- TokenWeave path: RS -> (+res, norm on 1/N tokens) -> AG -----------

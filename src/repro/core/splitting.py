@@ -50,44 +50,6 @@ def wave_count(n_tokens: int, unit: int) -> int:
     return math.ceil(n_tokens / unit)
 
 
-# ---- comm resource budget -> ring-lane (SM-equivalent) mapping ----------
-# The paper's fused multimem kernel runs on 2-8 SMs; the TPU ring kernel's
-# analogue resource is its comm-slot ("channel") count — the number of
-# in-flight ring lanes (kernels/ring_ar_rmsnorm.py).  A plan entry's
-# ``budget`` in (0, 1] is the SM-equivalent fraction granted to comm
-# (NeMo's per-op ``num_sm`` knob, DESIGN.md §14):
-#     channels = round(budget * MAX_RING_CHANNELS)
-# Deliberately NOT clamped to >= 1 here: scripts/check_plan.py rejects
-# plan entries whose budget maps to zero lanes (an overcommitted plan
-# would grant the kernel no comm resources at all); runtime callers clamp
-# with max(1, ...) after validation.
-MAX_RING_CHANNELS: int = 8
-
-
-def ring_channels(budget: float) -> int:
-    """SM-equivalent comm budget -> ring-lane count for the fused kernel."""
-    return int(round(float(budget) * MAX_RING_CHANNELS))
-
-
-# token-bucket edges shared by the overlap policy layer (core/policy.py,
-# DESIGN.md §14): a decision at n tokens falls in the bucket whose lower
-# edge is the largest edge <= n.  Kept here (pure token math) so both the
-# SplitDecision record and the plan cache key on the same labels.
-DEFAULT_BUCKET_EDGES: Tuple[int, ...] = (
-    0, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096, 8192, 16384)
-
-
-def token_bucket(n_tokens: int,
-                 edges: Tuple[int, ...] = DEFAULT_BUCKET_EDGES) -> str:
-    """Bucket label for a token count: ``"lo-hi"`` (inclusive) for bounded
-    buckets, ``"lo+"`` for the open last bucket."""
-    n = max(int(n_tokens), 0)
-    for lo, hi in zip(edges, edges[1:]):
-        if lo <= n < hi:
-            return f"{lo}-{hi - 1}"
-    return f"{edges[-1]}+"
-
-
 def split_sizes_for_batch(
     n_tokens: int,
     *,
@@ -95,11 +57,7 @@ def split_sizes_for_batch(
     min_tokens: int,
     row_multiple: int = 1,
 ) -> Optional[Tuple[int, int]]:
-    """Splitting decision used by the runtime — the degenerate
-    global-threshold form of the overlap policy (``core/policy.
-    ThresholdPolicy`` reproduces it token-identically; tuned per-site
-    plans override it via ``ParallelConfig.overlap_policy``, DESIGN.md
-    §14).
+    """The split ``split_decision`` picks, without its reason.
 
     ``row_multiple`` constrains the split point to a multiple of the batch
     size when tokens are laid out (B, S) row-major and we split along S (all
@@ -115,23 +73,17 @@ def split_sizes_for_batch(
 @dataclasses.dataclass(frozen=True)
 class SplitDecision:
     """Reasoned split decision (the trace-attribution record's core,
-    DESIGN.md §12): the split chosen — or None plus WHY not — stamped
-    with the overlap plan that produced it (DESIGN.md §14).
+    DESIGN.md §12): the split chosen — or None plus WHY not.
 
     reasons: ``split`` (weave fires), ``below_min_tokens`` (under the
     paper's ~1K-token bypass threshold), ``below_wave_floor`` (enough
     tokens nominally, but a cut could not avoid adding a wave — fewer
-    than two full tile units at the effective quantum), plus the tuned-
-    plan reasons ``plan_split`` / ``plan_unsplit`` when a
-    ``core/policy.TunedPolicy`` entry decided (DESIGN.md §14)."""
+    than two full tile units at the effective quantum)."""
     split: Optional[Tuple[int, int]]
     reason: str
     n_tokens: int
     unit: int                 # effective wave quantum (lcm w/ row_multiple)
     min_tokens: int
-    plan_id: int = 0          # 0 = degenerate global-threshold policy
-    bucket: str = ""          # tokens-bucket the decision was keyed on
-    budget: float = 1.0       # comm resource budget -> ring_channels()
 
 
 def split_decision(
@@ -141,43 +93,22 @@ def split_decision(
     min_tokens: int,
     row_multiple: int = 1,
 ) -> SplitDecision:
-    """``split_sizes_for_batch`` with the refusal reason attached —
-    identical decision, used by the observability layer (DESIGN.md §12)
-    to explain every weave/no-weave call per forward step.  ``plan_id``
-    is pinned 0: this IS the degenerate global-threshold plan the policy
-    layer falls back to (DESIGN.md §14)."""
+    """The weave split arithmetic (DESIGN.md §14): the wave-aware halves
+    of ``n_tokens``, or None with the reason — the one decision every
+    dispatch kind takes (``models.transformer.weave_decision_info`` picks
+    its unit and floor), and the record the observability layer
+    (DESIGN.md §12) attaches to every forward step."""
     eff_unit = math.lcm(unit, max(row_multiple, 1))
-    bucket = token_bucket(n_tokens)
     if n_tokens < min_tokens:
         return SplitDecision(None, "below_min_tokens", n_tokens, eff_unit,
-                             min_tokens, 0, bucket)
+                             min_tokens)
     if n_tokens < 2 * unit:
         return SplitDecision(None, "below_wave_floor", n_tokens, eff_unit,
-                             min_tokens, 0, bucket)
+                             min_tokens)
     split = smart_split(n_tokens, eff_unit)
     return SplitDecision(split, "split" if split is not None
                          else "below_wave_floor", n_tokens, eff_unit,
-                         min_tokens, 0, bucket)
-
-
-def plan_split(n_tokens: int, unit: int, frac: float
-               ) -> Optional[Tuple[int, int]]:
-    """Wave-conserving split at an arbitrary prefix-wave fraction (the
-    tuned-plan generalization of ``smart_split``, DESIGN.md §14).
-
-    The prefix takes ``k = floor(frac * total_waves)`` full waves (clamped
-    to [1, total_waves-1]), so every invariant of ``smart_split`` holds
-    for ANY frac: no extra wave, prefix split full-waves-only.
-    ``frac = 0.5`` reproduces ``smart_split`` exactly.
-    """
-    if unit <= 0:
-        raise ValueError(f"unit must be positive, got {unit}")
-    if n_tokens < 2 * unit:
-        return None
-    total_waves = math.ceil(n_tokens / unit)
-    k = min(max(int(frac * total_waves), 1), total_waves - 1)
-    l1 = k * unit
-    return l1, n_tokens - l1
+                         min_tokens)
 
 
 def packed_split(
@@ -186,11 +117,9 @@ def packed_split(
     unit: int,
     min_tokens: int,
 ) -> Optional[Tuple[int, int]]:
-    """Weave decision for a packed hybrid iteration (DESIGN.md §6), in
-    its degenerate global-threshold form — the engine's packed planner
-    consults the active ``OverlapPolicy`` through the same
-    ``SplitDecision`` format (``site="packed"``, DESIGN.md §14), of
-    which this is the pinned ``plan_id=0`` fallback.
+    """Weave decision for a packed hybrid iteration (DESIGN.md §6): the
+    split ``weave_decision_info`` takes at the packed site (DESIGN.md
+    §14).
 
     A packed plan concatenates prefill-chunk segments, single-token decode
     slots, and speculative verify windows along ONE flat token axis, so the

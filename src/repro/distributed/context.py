@@ -31,7 +31,6 @@ class CommCtx:
     use_pallas: bool = False
     interpret: bool = False        # pallas interpret mode (CPU validation)
     bf16_wire: bool = False        # pin collective dtype (see ParallelConfig)
-    comm_budget: float = 1.0       # SM-equivalent fraction -> ring channels
 
     @property
     def sharded_residual(self) -> bool:

@@ -19,10 +19,10 @@ there on the serving hot path (``comm_norm`` mode="ring", DESIGN.md §2)
 whenever the backend supports it, falling back to that composition
 otherwise.
 
-The ``channels`` knob is the TPU analogue of the paper's 2-8 SM resource
-grant: it sizes the in-flight comm-slot ring lanes (HBM staging slots +
-their semaphores), mapped from a plan entry's SM-equivalent ``budget`` by
-``core.splitting.ring_channels`` (DESIGN.md §14).
+``channels`` sizes the comm slots the ring cycles through (HBM staging
+slots + their semaphores). Every DMA the kernel starts is waited on
+before the next step, so more slots add no concurrency; ``comm_norm``
+passes a fixed count.
 
 Numerics are pinned against kernels/ref.ring_ar_rmsnorm_ref and the
 unfused vanilla composition by tests/test_fused_path.py (in-process and
@@ -155,9 +155,9 @@ def ring_fused_ar_rmsnorm(x, residual, weight, *, axis_name: str,
     x: (T, d) per-device partial sums; residual: (T//n_dev, d) own token
     slice; weight: (d,). Returns (normed_full (T, d), new_residual).
 
-    ``channels`` = in-flight ring comm lanes (the SM-equivalent resource
-    grant; see module docstring). Clamped to [1, n_dev-1] — more lanes
-    than ring hops buys nothing.
+    ``channels`` = comm slots the ring cycles through (see module
+    docstring). Clamped to [1, n_dev-1] — more slots than ring hops buy
+    nothing.
     """
     t_tokens, d = x.shape
     assert t_tokens % n_dev == 0

@@ -39,10 +39,7 @@ from jax import lax
 
 from repro.configs.base import ModelConfig, ParallelConfig
 from repro.core import fused_collectives as fc
-from repro.core.splitting import split_decision, token_bucket  # noqa: F401
-#   (split_decision re-exported: tests + obs treat this module as the
-#    decision surface; the actual dispatch goes through the overlap
-#    policy, DESIGN.md §14)
+from repro.core.splitting import split_decision
 from repro.distributed.context import CommCtx
 from repro.layers import attention as A
 from repro.layers import embedding as E
@@ -345,165 +342,93 @@ class WeaveInfo:
     """Full weave decision for one forward dispatch: the split (in the
     dispatch's native axis units), WHY it was or wasn't taken, and the
     parameters the decision saw — the host-side record the observability
-    layer attaches to every forward span (DESIGN.md §12), stamped with
-    the overlap plan that produced it (DESIGN.md §14)."""
+    layer attaches to every forward span (DESIGN.md §12)."""
     weave: bool
     split: Optional[Tuple[int, int]]
     reason: str   # split | weave_disabled | paged_pool_unsplit |
-    #               below_min_tokens | below_wave_floor |
-    #               plan_split | plan_unsplit
+    #               below_min_tokens | below_wave_floor
     axis: str     # packed | batch | seq
     threshold: int  # configured tokenweave_min_tokens (tokens)
     unit: int       # effective wave quantum the decision used
-    site: str = ""      # policy decision site: prefill|decode|verify|packed
-    plan_id: int = 0    # 0 = degenerate global-threshold policy
-    bucket: str = ""    # tokens-bucket the decision was keyed on
-    budget: float = 1.0   # comm resource-budget fraction the plan granted
-    sim_method: str = ""  # plan-forced sim pricing mode; "" = legacy
-    #                       comm-mode mapping (obs/attribution.py)
-    comm_mode: str = ""   # plan-forced comm_norm mode ("ring" when the
-    #                       plan dispatches the real fused kernel); "" =
-    #                       pcfg.comm_mode
-
-
-def _active_policy(pcfg: ParallelConfig):
-    from repro.core.policy import DEFAULT_POLICY
-    return pcfg.overlap_policy or DEFAULT_POLICY
-
-
-def _plan_meta(policy, site: str, tokens: int, tp: int, family: str,
-               has_split: bool = False) -> Tuple[float, str, str]:
-    """(budget, sim_method, comm_mode) granted by the active plan.
-
-    sim_method stays "" (= the legacy comm-mode mapping in
-    obs/attribution.py) unless a plan entry forces a different pricing
-    mode: ``none`` disables the fused collective entirely -> vanilla;
-    the fused methods price as the ring kernel (``ring`` unsplit,
-    ``ringweave`` when the split actually fired).  comm_mode is "ring"
-    for the fused methods — ``forward`` threads it into ``_comm_ctx`` so
-    ``comm_norm`` dispatches the real kernel (DESIGN.md §2)."""
-    plan = policy.plan_for(site, tokens, tp=tp, family=family)
-    if plan is None:
-        return 1.0, "", ""
-    if plan.method == "none":
-        return plan.budget, "vanilla", ""
-    if plan.method == "fused-unsplit":
-        return plan.budget, "ring", "ring"
-    if plan.method == "fused":
-        return plan.budget, "ringweave" if has_split else "ring", "ring"
-    return plan.budget, "", ""
+    site: str       # dispatch kind: prefill | decode | verify | packed
 
 
 def weave_decision_info(b: int, s: int, *, tp: int, pcfg: ParallelConfig,
                         decode: bool = False, packed: bool = False,
-                        paged_pool: bool = False,
-                        family: str = "dense") -> WeaveInfo:
-    """Host-side mirror of the trace-time weave split decision (pure int
-    math), with the refusal reason attached.
+                        paged_pool: bool = False) -> WeaveInfo:
+    """The weave rule (DESIGN.md §14): the host-side mirror of the
+    trace-time split decision (pure int math), with the refusal reason
+    attached.
 
-    The decision is delegated to the active ``OverlapPolicy``
-    (``pcfg.overlap_policy``, DESIGN.md §14) at one of four sites —
-    prefill/train: split along the sequence dim (all rows cut at the same
-    position — rectangular shapes); decode: split along the batch dim;
-    verify: decode with s = gamma+1 tokens per row; packed: split along
-    the flat packed token axis (b == 1), so the threshold sees the true
-    combined iteration size (DESIGN.md §6).  Without an installed policy
-    the degenerate global-threshold ``ThresholdPolicy`` applies — token-
-    identical to the historical ``split_decision`` path.
+    One ``split_decision`` per dispatch kind — prefill: split along the
+    sequence dim (all rows cut at the same position — rectangular
+    shapes), unit ``split_unit_for(tp)``, floor ``tokenweave_min_tokens``;
+    decode: split along the batch dim, unit ``max(tp, 8)`` rows, floor two
+    units; verify: decode with s = gamma+1 tokens per row, the token floor
+    converted to rows; packed: split along the flat packed token axis
+    (b == 1), so the threshold sees the true combined iteration size
+    (DESIGN.md §6).
     ``paged_pool`` marks a non-packed paged decode/verify dispatch, which
     always runs unsplit (a batch split would fork the shared pool,
     DESIGN.md §7); packed paged steps thread the pool sequentially
     through the splits and CAN weave.
     """
     thr = pcfg.tokenweave_min_tokens
-    policy = _active_policy(pcfg)
-    pid = getattr(policy, "plan_id", 0)
+    site = ("packed" if packed else "prefill" if not decode else
+            "decode" if s == 1 else "verify")
+    axis = "packed" if packed else ("batch" if decode else "seq")
     if not pcfg.tokenweave:
-        return WeaveInfo(False, None, "weave_disabled", "packed" if packed
-                         else ("batch" if decode else "seq"), thr, 0,
-                         site="packed" if packed else (
-                             "decode" if decode and s == 1 else
-                             "verify" if decode else "prefill"),
-                         plan_id=pid, bucket=token_bucket(b * s))
+        return WeaveInfo(False, None, "weave_disabled", axis, thr, 0, site)
     if paged_pool and not packed:
-        # the shared pool forbids a batch split, but the plan's METHOD
-        # still applies: a fused entry dispatches the ring kernel unsplit
-        site = ("decode" if decode and s == 1 else
-                "verify" if decode else "prefill")
-        budget, sim, cm = _plan_meta(policy, site, b * s, tp, family,
-                                     has_split=False)
-        return WeaveInfo(False, None, "paged_pool_unsplit",
-                         "batch" if decode else "seq", thr, 0,
-                         site=site, plan_id=pid, bucket=token_bucket(b * s),
-                         budget=budget, sim_method=sim, comm_mode=cm)
+        return WeaveInfo(False, None, "paged_pool_unsplit", axis, thr, 0,
+                         site)
     if packed:
-        d = policy.decide("packed", b * s, unit=pcfg.split_unit_for(tp),
-                          min_tokens=thr, tp=tp, family=family)
-        budget, sim, cm = _plan_meta(policy, "packed", b * s, tp, family,
-                                     has_split=d.split is not None)
-        return WeaveInfo(d.split is not None, d.split, d.reason, "packed",
-                         thr, d.unit, site="packed", plan_id=d.plan_id,
-                         bucket=d.bucket, budget=budget, sim_method=sim,
-                         comm_mode=cm)
-    if decode:
+        d = split_decision(b * s, unit=pcfg.split_unit_for(tp),
+                           min_tokens=thr)
+        split = d.split
+    elif decode:
         unit = max(tp, 8)
-        if s > 1:
-            # speculative verify: every batch row carries s = gamma+1
-            # tokens, so the paper's token threshold converts to rows —
-            # this is exactly how spec decoding pushes decode iterations
-            # across tokenweave_min_tokens (DESIGN.md §8)
-            min_rows = max(2 * unit, -(-thr // s))
-            d = policy.decide("verify", b, unit=unit, min_tokens=min_rows,
-                              tp=tp, family=family, bucket_tokens=b * s)
-            site = "verify"
-        else:
-            d = policy.decide("decode", b, unit=unit, min_tokens=2 * unit,
-                              tp=tp, family=family)
-            site = "decode"
-        budget, sim, cm = _plan_meta(policy, site, b * s, tp, family,
-                                     has_split=d.split is not None)
-        return WeaveInfo(d.split is not None, d.split, d.reason, "batch",
-                         thr, d.unit, site=site, plan_id=d.plan_id,
-                         bucket=d.bucket, budget=budget, sim_method=sim,
-                         comm_mode=cm)
-    d = policy.decide("prefill", b * s, unit=pcfg.split_unit_for(tp),
-                      min_tokens=thr, row_multiple=b, tp=tp, family=family)
-    split = None if d.split is None else (d.split[0] // b, d.split[1] // b)
-    budget, sim, cm = _plan_meta(policy, "prefill", b * s, tp, family,
-                                 has_split=split is not None)
-    return WeaveInfo(split is not None, split, d.reason, "seq", thr, d.unit,
-                     site="prefill", plan_id=d.plan_id, bucket=d.bucket,
-                     budget=budget, sim_method=sim, comm_mode=cm)
+        # speculative verify: every batch row carries s = gamma+1 tokens,
+        # so the paper's token threshold converts to rows — this is
+        # exactly how spec decoding pushes decode iterations across
+        # tokenweave_min_tokens (DESIGN.md §8)
+        min_rows = max(2 * unit, -(-thr // s)) if s > 1 else 2 * unit
+        d = split_decision(b, unit=unit, min_tokens=min_rows)
+        split = d.split
+    else:
+        d = split_decision(b * s, unit=pcfg.split_unit_for(tp),
+                           min_tokens=thr, row_multiple=b)
+        split = None if d.split is None else (d.split[0] // b,
+                                              d.split[1] // b)
+    return WeaveInfo(split is not None, split, d.reason, axis, thr, d.unit,
+                     site)
 
 
 def _decide_split(b: int, s: int, *, tp: int, pcfg: ParallelConfig,
-                  decode: bool, packed: bool = False,
-                  family: str = "dense") -> Optional[Tuple[int, int]]:
+                  decode: bool, packed: bool = False
+                  ) -> Optional[Tuple[int, int]]:
     """Static (trace-time) TokenWeave split decision (per-dim sizes or
     None) — thin view over ``weave_decision_info``."""
     return weave_decision_info(b, s, tp=tp, pcfg=pcfg, decode=decode,
-                               packed=packed, family=family).split
+                               packed=packed).split
 
 
 def weave_decision(b: int, s: int, *, tp: int, pcfg: ParallelConfig,
                    decode: bool = False, packed: bool = False,
-                   paged_pool: bool = False, family: str = "dense") -> bool:
+                   paged_pool: bool = False) -> bool:
     """Boolean view of ``weave_decision_info`` (the engine's legacy
     weave-activation predicate)."""
     return weave_decision_info(b, s, tp=tp, pcfg=pcfg, decode=decode,
-                               packed=packed, paged_pool=paged_pool,
-                               family=family).weave
+                               packed=packed, paged_pool=paged_pool).weave
 
 
-def comm_mode_for(pcfg: ParallelConfig, t_local: int, tp: int,
-                  mode: Optional[str] = None) -> str:
+def comm_mode_for(pcfg: ParallelConfig, t_local: int, tp: int) -> str:
     """The comm mode a forward over ``t_local`` tokens runs at every
-    ``comm_norm`` site: ``mode`` (the overlap plan's, "ring" = the real
-    fused kernel, DESIGN.md §14) or ``pcfg.comm_mode``, except that the
-    token-sharded (fused/reordered/ring) layouts need t_local divisible by
-    tp and fall back to vanilla otherwise (the paper's fallback for small
-    decode batches)."""
-    mode = mode or pcfg.comm_mode
+    ``comm_norm`` site (DESIGN.md §14): ``pcfg.comm_mode``, except that
+    the token-sharded (fused/reordered/ring) layouts need t_local
+    divisible by tp and fall back to vanilla otherwise (the paper's
+    fallback for small decode batches)."""
+    mode = pcfg.comm_mode
     if (mode in ("fused", "reordered", "ring")
             and (t_local % tp != 0 or t_local < tp)):
         return "vanilla"
@@ -511,15 +436,12 @@ def comm_mode_for(pcfg: ParallelConfig, t_local: int, tp: int,
 
 
 def _comm_ctx(pcfg: ParallelConfig, cfg: ModelConfig, t_local: int,
-              tp: int, *, mode: Optional[str] = None,
-              budget: float = 1.0) -> CommCtx:
-    """The forward's ``CommCtx``, in the mode ``comm_mode_for`` picks;
-    ``budget`` is the plan's comm resource grant, sizing the ring kernel's
-    lanes."""
-    mode = comm_mode_for(pcfg, t_local, tp, mode)
-    return CommCtx(tp_axis=pcfg.tp_axis, dp_axes=pcfg.dp_axes, mode=mode,
+              tp: int) -> CommCtx:
+    """The forward's ``CommCtx``, in the mode ``comm_mode_for`` picks."""
+    return CommCtx(tp_axis=pcfg.tp_axis, dp_axes=pcfg.dp_axes,
+                   mode=comm_mode_for(pcfg, t_local, tp),
                    eps=cfg.norm_eps, use_pallas=pcfg.use_pallas_norm,
-                   bf16_wire=pcfg.bf16_wire, comm_budget=budget)
+                   bf16_wire=pcfg.bf16_wire)
 
 
 def _entry_norm(emb, w_first, ctx):
@@ -566,10 +488,8 @@ def forward(params, tokens, *, cfg: ModelConfig, pcfg: ParallelConfig,
     packed = packed_slots is not None
     winfo = weave_decision_info(
         b, s_total, tp=tp, pcfg=pcfg, decode=decode, packed=packed,
-        paged_pool=(decode and block_tables is not None and not packed),
-        family=cfg.family)
-    ctx = _comm_ctx(pcfg, cfg, b * s_total, tp,
-                    mode=winfo.comm_mode or None, budget=winfo.budget)
+        paged_pool=(decode and block_tables is not None and not packed))
+    ctx = _comm_ctx(pcfg, cfg, b * s_total, tp)
     emb = E.embed_tokens(params["embedding"], tokens, tp_axis=ctx.tp_axis,
                          scale=cfg.embed_scale)
     if extra_embeds is not None:

@@ -11,14 +11,8 @@ virtual time for this forward (``sim.overlap_sim.step_attribution``).
 
 The ``Attributor`` prices with ``HW(tile=pcfg.split_unit_for(tp))`` so
 the sim's split decisions quantize at the same wave unit the engine
-actually uses, and memoizes by (mode, tokens, budget): a steady decode
-loop prices each distinct batch size once.
-
-Since DESIGN.md §14 the decision may come from a tuned per-site overlap
-plan rather than the global threshold: each record then carries the plan
-id + tokens-bucket that keyed the plan entry, the sim pricing follows
-the plan's method (``sim_method``) and resource budget, and a tuned
-split point is priced explicitly instead of re-derived by the sim.
+actually uses, and memoizes by (mode, tokens): a steady decode loop
+prices each distinct batch size once.
 """
 from __future__ import annotations
 
@@ -40,8 +34,7 @@ class WeaveAttribution:
     tokens_static: int   # b * s — what the split decision saw
     weave: bool
     reason: str          # split | below_min_tokens | below_wave_floor |
-    #                      weave_disabled | paged_pool_unsplit |
-    #                      plan_split | plan_unsplit
+    #                      weave_disabled | paged_pool_unsplit
     split: Optional[Tuple[int, int]]
     method: str          # tokenweave | ringweave | ring | fuseonly |
     #                      reordered | vanilla
@@ -51,9 +44,6 @@ class WeaveAttribution:
     est_comm: float
     est_overlapped: float
     est_makespan: float
-    plan_id: int = 0     # overlap plan that decided (0 = global threshold)
-    bucket: str = ""     # tokens-bucket the plan lookup keyed on
-    budget: float = 1.0  # comm resource-budget fraction the plan granted
 
     def args(self) -> dict:
         """JSON-able Chrome-trace ``args`` payload; carries every field
@@ -67,8 +57,6 @@ class WeaveAttribution:
             "threshold": self.threshold,
             "split": list(self.split) if self.split else None,
             "method": self.method,
-            "plan_id": self.plan_id,
-            "bucket": self.bucket,
             "est_compute": round(self.est_compute, 9),
             "est_comm": round(self.est_comm, 9),
             "est_overlapped": round(self.est_overlapped, 9),
@@ -85,41 +73,26 @@ class Attributor:
         self.hw = HW(tile=pcfg.split_unit_for(self.tp))
         self._cache: Dict[Tuple, Dict[str, float]] = {}
 
-    def price(self, mode: str, tokens: int,
-              split: Optional[Tuple[int, int]] = None,
-              budget: float = 1.0) -> Dict[str, float]:
-        key = (mode, tokens, split, budget)
+    def price(self, mode: str, tokens: int) -> Dict[str, float]:
+        key = (mode, tokens)
         got = self._cache.get(key)
         if got is None:
             got = self._cache[key] = step_attribution(
-                self.cfg, mode, max(tokens, 1), tp=self.tp, hw=self.hw,
-                split=split,
-                comm_budget=None if budget == 1.0 else budget)
+                self.cfg, mode, max(tokens, 1), tp=self.tp, hw=self.hw)
         return got
 
     def attribute(self, info: WeaveInfo, *, b: int, s: int, n_real: int,
                   kind: str) -> WeaveAttribution:
-        if info.sim_method:
-            # a tuned plan entry forced this pricing mode (DESIGN.md §14);
-            # checked BEFORE info.weave so a fused plan split prices as
-            # ringweave, not as the composed tokenweave
-            method = info.sim_method
-        elif info.weave:
+        if info.weave:
             method = "tokenweave"
         else:
             method = {"fused": "fuseonly",
                       "reordered": "reordered"}.get(self.pcfg.comm_mode,
                                                     "vanilla")
-        # a tuned (plan_split) weave carries an explicit split point the
-        # sim must price verbatim; legacy splits re-derive inside the sim
-        # (identical by construction, and token counts may be row counts)
-        split = (info.split if info.weave and info.reason == "plan_split"
-                 and info.axis == "packed" else None)
-        est = self.price(method, b * s, split=split, budget=info.budget)
+        est = self.price(method, b * s)
         return WeaveAttribution(
             kind=kind, b=b, s=s, tokens_real=n_real, tokens_static=b * s,
             weave=info.weave, reason=info.reason, split=info.split,
             method=method, threshold=info.threshold, unit=info.unit,
             est_compute=est["compute"], est_comm=est["comm"],
-            est_overlapped=est["overlapped"], est_makespan=est["makespan"],
-            plan_id=info.plan_id, bucket=info.bucket, budget=info.budget)
+            est_overlapped=est["overlapped"], est_makespan=est["makespan"])

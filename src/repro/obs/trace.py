@@ -266,7 +266,7 @@ def validate_chrome_trace(doc: dict) -> List[str]:
 
     eps = 1e-3  # µs — float slack on nested span edges
     required = ("weave", "reason", "tokens", "threshold", "method",
-                "plan_id", "est_compute", "est_comm", "est_overlapped")
+                "est_compute", "est_comm", "est_overlapped")
     for key, t0, t1, args in forwards:
         if not any(s0 - eps <= t0 and t1 <= s1 + eps
                    for s0, s1 in steps.get(key, [])):

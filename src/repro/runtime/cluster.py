@@ -99,10 +99,6 @@ class ClusterConfig:
     migration_cost: MigrationCost = dataclasses.field(
         default_factory=MigrationCost)
     max_steps: int = 1_000_000        # total engine steps across the fleet
-    # tuned overlap-plan cache installed on EVERY replica engine at
-    # cluster startup (core/policy.py, DESIGN.md §14); None keeps each
-    # engine's own policy
-    plan_path: Optional[str] = None
     # --- wire transport (DESIGN.md §15) ---
     # None: in-process object passing (the §11 default).  "loopback":
     # every arrival envelope and migration payload round-trips the frame
@@ -358,14 +354,6 @@ class ClusterServer:
         for rep in replicas:
             if rep.step_cost is None:
                 rep.step_cost = self.cfg.step_cost
-        if self.cfg.plan_path:
-            # one tuned plan for the whole fleet (DESIGN.md §14): each
-            # replica installs the same policy, so routing decisions never
-            # change which overlap scheme a request's tokens see
-            from repro.core.policy import load_policy
-            policy = load_policy(self.cfg.plan_path)
-            for rep in replicas:
-                rep.engine.install_overlap_policy(policy)
 
         prefill = [r for r in replicas if r.role == "prefill"]
         decode = [r for r in replicas if r.role == "decode"]

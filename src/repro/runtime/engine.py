@@ -284,15 +284,6 @@ class Engine:
                  obs: TraceRecorder | None = None,
                  obs_track: str = "engine",
                  profiler: "WallClockProfiler | None" = None):
-        if scfg.plan_path:
-            # tuned overlap-plan cache (core/policy.py, DESIGN.md §14):
-            # install the policy on the model's ParallelConfig BEFORE any
-            # jit cache or attributor is built, so every consumer —
-            # forward dispatch, packed planner, attribution — sees it
-            from repro.core.policy import load_policy
-            api = dataclasses.replace(
-                api, pcfg=dataclasses.replace(
-                    api.pcfg, overlap_policy=load_policy(scfg.plan_path)))
         self.api = api
         self.mesh = mesh
         self.params = params
@@ -302,9 +293,6 @@ class Engine:
         self.top_p = top_p
         self.metrics = MetricsRegistry()
         self.stats = EngineStats(self.metrics)
-        self.metrics.gauge("engine/plan_id").set(
-            getattr(api.pcfg.overlap_policy, "plan_id", 0)
-            if api.pcfg.overlap_policy is not None else 0)
         # tracing (DESIGN.md §12): obs is None by default — every obs code
         # path is behind an ``is not None`` guard, so tracing off costs
         # nothing and (invariant) tracing on changes no tokens or steps
@@ -401,12 +389,7 @@ class Engine:
             cspec = api.cache_specs()
         self.sched = Scheduler(
             scfg, block_mgr=self.block_mgr,
-            on_admit=self._obs_admit if obs is not None else None,
-            # the packed planner consumes the SAME per-site overlap plan
-            # as the forward dispatch (DESIGN.md §14): a late-binding
-            # closure over self.api, so install_overlap_policy() swaps
-            # the planner's view too
-            overlap_hint=self._overlap_hint if self.packed else None)
+            on_admit=self._obs_admit if obs is not None else None)
         # disaggregated serving (DESIGN.md §11): requests parked by
         # ``_park_for_handoff`` wait here for the cluster to migrate them
         self.handoff_ready: List[Handoff] = []
@@ -449,35 +432,6 @@ class Engine:
             outs = [np.asarray(o) for o in outs]
         self._dispatch_s = time.perf_counter() - t0
         return outs
-
-    # ------------------------------------------------------------------
-    # per-site overlap policy (core/policy.py, DESIGN.md §14)
-    # ------------------------------------------------------------------
-    def _overlap_hint(self, tokens: int) -> TRX.WeaveInfo:
-        """The packed planner's view of the active overlap policy: the
-        same ``weave_decision_info`` the packed forward dispatch will run
-        for ``tokens``, stamped on ``PackedPlan.overlap`` — one plan
-        format everywhere."""
-        return TRX.weave_decision_info(
-            1, tokens, tp=self.api.tp, pcfg=self.api.pcfg, packed=True,
-            family=self.api.cfg.family)
-
-    def install_overlap_policy(self, policy) -> None:
-        """Swap the active ``OverlapPolicy`` (e.g. a freshly loaded tuned
-        plan).  The policy lives on the model's ``ParallelConfig``, which
-        is baked into jitted step closures and the attributor — so both
-        are rebuilt; in-flight requests and caches are untouched (the
-        policy only picks split points, never shapes semantics)."""
-        self.api = dataclasses.replace(
-            self.api, pcfg=dataclasses.replace(self.api.pcfg,
-                                               overlap_policy=policy))
-        self._jit_cache = {}
-        self._programs_run = set()
-        if self._attributor is not None:
-            self._attributor = Attributor(self.api.cfg, self.api.pcfg,
-                                          self.api.tp)
-        self.metrics.gauge("engine/plan_id").set(
-            getattr(policy, "plan_id", 0) if policy is not None else 0)
 
     # ------------------------------------------------------------------
     # jitted step functions
@@ -724,17 +678,6 @@ class Engine:
         st = self.stats
         m.gauge("engine/weave_rate").set(st.weave_rate)
         m.gauge("engine/tokens_per_forward").set(st.tokens_per_forward)
-        # per-site weave rates (overlap policy attribution, DESIGN.md §14)
-        for (name, lk), inst in list(m._instruments.items()):
-            if name != "engine/site_forwards" or not inst.value:
-                continue
-            labels = dict(lk)
-            w = m.get("engine/site_weave", **labels)
-            m.gauge("engine/site_weave_rate", **labels).set(
-                (w.value if w is not None else 0) / inst.value)
-            fz = m.get("engine/site_fused", **labels)
-            m.gauge("engine/site_fused_rate", **labels).set(
-                (fz.value if fz is not None else 0) / inst.value)
         m.gauge("spec/acceptance_rate").set(st.spec.acceptance_rate)
         m.gauge("spec/tokens_per_step").set(st.spec.tokens_per_step)
         m.gauge("latency/goodput").set(st.latency.goodput)
@@ -967,10 +910,8 @@ class Engine:
         info = TRX.weave_decision_info(b, s, tp=self.api.tp,
                                        pcfg=self.api.pcfg, decode=decode,
                                        packed=packed,
-                                       paged_pool=self.paged and decode,
-                                       family=self.api.cfg.family)
-        return info, TRX.comm_mode_for(self.api.pcfg, b * s, self.api.tp,
-                                       info.comm_mode or None)
+                                       paged_pool=self.paged and decode)
+        return info, TRX.comm_mode_for(self.api.pcfg, b * s, self.api.tp)
 
     def _note_forward(self, info: TRX.WeaveInfo, comm: str, b: int, s: int,
                       n_real: int, *, kind: str = "prefill"):
@@ -984,22 +925,13 @@ class Engine:
         st._forwards.inc()
         st._forward_tokens.inc(n_real)
         st._max_forward_tokens.set_max(n_real)
-        # per-site weave attribution (DESIGN.md §14): which policy site
-        # decided, and whether the weave fired there
-        site = info.site or kind
-        self.metrics.counter("engine/site_forwards", site=site).inc()
         if info.weave:
             st._weave_forwards.inc()
-            self.metrics.counter("engine/site_weave", site=site).inc()
-        if info.comm_mode == "ring":
-            # a tuned plan routed this site onto the REAL fused ring
-            # AllReduce-RMSNorm kernel (method fused / fused-unsplit)
-            self.metrics.counter("engine/site_fused", site=site).inc()
         if comm == "vanilla":
             # every comm_norm site of the dispatch ran the plain psum: the
             # configured mode, or the fallback where tp does not divide
             # the dispatch's tokens
-            self.metrics.counter("engine/comm_vanilla", site=site).inc()
+            self.metrics.counter("engine/comm_vanilla", site=info.site).inc()
         if self._attributor is not None:
             att = self._attributor.attribute(info, b=b, s=s, n_real=n_real,
                                              kind=kind)

@@ -11,7 +11,8 @@ The two are dispatched as two forward calls per iteration (documented
 simplification vs. packed ragged hybrid batches, DESIGN.md §6). TokenWeave
 is applied inside the model per batch: chunks >= ``tokenweave_min_tokens``
 take the two-split weave; small decode batches fall back to the unsplit
-fused kernel — the same policy the paper uses for vLLM integration.
+fused kernel — the same rule the paper uses for vLLM integration
+(``models.transformer.weave_decision_info``, DESIGN.md §14).
 
 Paged mode (``SchedulerConfig.paged``) changes admission and accounting:
 
@@ -73,13 +74,18 @@ class SchedulerConfig:
     #         without a deadline sort last, FCFS among themselves).
     # A callable can be plugged directly via ``Scheduler(..., policy=fn)``.
     policy: str = "fcfs"
-    # --- overlap policy plan cache (core/policy.py, DESIGN.md §14) ---
-    # path to a tuned-plan JSON under benchmarks/plans/; the engine loads
-    # it at startup and installs the TunedPolicy on the model's
-    # ParallelConfig.  None keeps the degenerate global threshold.
+    # --- accepted only as None, so configurations that still carry the
+    # key keep loading: the weave split and comm mode follow one rule,
+    # models.transformer.weave_decision_info (DESIGN.md §14), and there
+    # is no plan to load ---
     plan_path: Optional[str] = None
 
     def __post_init__(self):
+        if self.plan_path is not None:
+            raise ValueError(
+                f"plan_path={self.plan_path!r}: the tuned overlap-plan "
+                f"cache was removed; the weave split and comm mode follow "
+                f"one rule (DESIGN.md §14), so plan_path must be None")
         if self.policy not in ADMISSION_POLICIES:
             raise ValueError(
                 f"unknown admission policy {self.policy!r}; known: "
@@ -145,33 +151,22 @@ class PackedSegment:
 class PackedPlan:
     segments: List[PackedSegment]
     total_tokens: int               # sum of budgeted segment tokens
-    # the overlap decision for this plan (a models.transformer.WeaveInfo),
-    # stamped by the engine's overlap hint at planning time so the packed
-    # planner and the forward dispatch consume ONE plan format
-    # (DESIGN.md §14); None until the hint runs (or when no hint is wired)
-    overlap: Optional[object] = None
 
 
 class Scheduler:
     def __init__(self, cfg: SchedulerConfig, block_mgr=None, policy=None,
-                 on_admit=None, overlap_hint=None):
+                 on_admit=None):
         self.cfg = cfg
         self.block_mgr = block_mgr          # BlockManager when cfg.paged
         self.waiting: List[Request] = []
         self.active: List[Optional[Request]] = [None] * cfg.max_batch
         self.finished: List[Request] = []
         # pluggable priority: explicit callable wins, else the named policy
-        # (NB: ``policy`` here is the ADMISSION policy — the per-site
-        # OVERLAP policy arrives through ``overlap_hint`` below)
         self.policy_key = (policy if policy is not None
                            else ADMISSION_POLICIES[cfg.policy])
         # observation-only admission hook (the engine's trace recorder,
         # DESIGN.md §12) — fired after the request lands in its slot
         self.on_admit = on_admit
-        # tokens -> WeaveInfo: the engine's view of the active overlap
-        # policy at the packed site (DESIGN.md §14); stamps
-        # PackedPlan.overlap so the planner shares the dispatch's plan
-        self.overlap_hint = overlap_hint
 
     # ---- admission -------------------------------------------------------
     def add(self, req: Request):
@@ -298,11 +293,8 @@ class Scheduler:
             budget -= take
         if not segs:
             return None
-        plan = PackedPlan(segments=segs,
+        return PackedPlan(segments=segs,
                           total_tokens=sum(s.n_tokens for s in segs))
-        if self.overlap_hint is not None:
-            plan.overlap = self.overlap_hint(plan.total_tokens)
-        return plan
 
     # ---- bookkeeping ------------------------------------------------------
     def finish(self, req: Request, step: int):

@@ -79,9 +79,6 @@ class ServerConfig:
     # letting them finish late; both outcomes count against goodput
     expire_on_deadline: bool = False
     max_steps: int = 1_000_000
-    # tuned overlap-plan cache to install on the engine at server startup
-    # (core/policy.py, DESIGN.md §14); None keeps the engine's own policy
-    plan_path: Optional[str] = None
 
 
 class OnlineServer:
@@ -100,12 +97,6 @@ class OnlineServer:
     def __init__(self, engine: Engine, cfg: Optional[ServerConfig] = None):
         self.engine = engine
         self.cfg = cfg or ServerConfig()
-        if self.cfg.plan_path:
-            # serving deployments ship a tuned per-site overlap plan
-            # (DESIGN.md §14); installed before the first step so every
-            # dispatch and the packed planner see it
-            from repro.core.policy import load_policy
-            engine.install_overlap_policy(load_policy(self.cfg.plan_path))
         self.clock = 0.0
         self.requests: List[Request] = []           # every submit, any fate
         self.completed: List[Request] = []

@@ -715,12 +715,6 @@ class RemoteEngine:
         return int(self._rpc("prefix_hits",
                              {"hashes": [int(h) for h in hashes]})["hits"])
 
-    def install_overlap_policy(self, policy) -> None:
-        # remote hosts load their plan from their own spec at launch; the
-        # frontend cannot ship a live policy object over the wire
-        raise ValueError("install_overlap_policy is not supported on "
-                         "RemoteEngine — pass plan_path in the host spec")
-
     def evacuate(self) -> List[Request]:
         """Dead-replica recovery (no RPC — the peer is gone): hand every
         live mirrored request back, reset for re-admission elsewhere."""

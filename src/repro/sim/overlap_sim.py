@@ -18,11 +18,9 @@ Modes (match core.fused_collectives + the weave):
                the pre-ring full TokenWeave)
     ring       serial: the REAL one-kernel ring AllReduce-RMSNorm
                (kernels/ring_ar_rmsnorm.py) — norm math never leaves
-               VMEM, priced from its ring-lane resource budget
-               (``ring_channels``, DESIGN.md §14) instead of the generic
-               contention model
+               VMEM
     ringweave  ring kernel + two-split overlap — the full TokenWeave
-               configuration the paper ships (plan method ``fused``)
+               configuration the paper ships
     nocomm     collectives removed (paper vllm-nocomm counterfactual)
 
 Speculative decoding (``spec_decode_latency`` / ``spec_decode_summary``)
@@ -38,8 +36,7 @@ from typing import Dict, List, Optional, Tuple
 
 from repro.analysis.roofline import HBM_BW, ICI_EFF, PEAK_FLOPS
 from repro.configs.base import ModelConfig
-from repro.core.splitting import (MAX_RING_CHANNELS, naive_split,
-                                  ring_channels, smart_split)
+from repro.core.splitting import naive_split, smart_split
 
 BYTES = 2  # bf16
 
@@ -181,97 +178,41 @@ def t_ring_norm(tokens: int, d: int, hw: HW) -> float:
 # schedules
 # --------------------------------------------------------------------------
 
-# comm-budget contention model (DESIGN.md §14): a tuned plan may cap the
-# fraction b of interconnect/SM resources the fused collective kernel
-# claims (Flash Communication's knob).  b scales wire bandwidth directly
-# (ici_eff = ici*b) and RELIEVES compute by the share of the MFU cap the
-# resident comm kernel taxes: mfu_eff = mfu_cap*(1 - TAX*b)/(1 - TAX),
-# normalized so b = 1.0 reproduces the legacy single-hw numbers bit-exactly
-# (the default mfu_cap already prices a full-budget comm kernel).
-_BUDGET_TAX = 0.2
-
-
-def _budgeted(hw: HW, comm_budget: Optional[float]) -> Tuple[HW, HW]:
-    """(hw_compute, hw_comm) under comm resource-budget fraction b."""
-    if comm_budget is None or comm_budget == 1.0:
-        return hw, hw
-    b = comm_budget
-    if not (0.0 < b <= 1.0):
-        raise ValueError(f"comm_budget must be in (0, 1], got {b}")
-    hw_comm = dataclasses.replace(hw, ici=hw.ici * b)
-    mfu = hw.mfu_cap * (1.0 - _BUDGET_TAX * b) / (1.0 - _BUDGET_TAX)
-    hw_compute = dataclasses.replace(hw, mfu_cap=mfu)
-    return hw_compute, hw_comm
-
-
-# ring-kernel resource model (DESIGN.md §14): the fused ring kernel's
-# resource grant is its LANE COUNT c = ring_channels(budget), the paper's
-# 2-8 SM knob.  A few lanes already saturate the wire (the paper's fused
-# kernel holds AR bandwidth with 2-8 of 132 SMs): wire efficiency is
-# min(1, c/_RING_SAT).  Compute is relieved by the lanes NOT granted —
-# the same MFU-tax shape as ``_budgeted`` with b_eff = c/MAX_RING_CHANNELS
-# — so a half-budget ring entry keeps full wire speed while returning
-# compute, which is exactly why the tuner prefers it over the composed
-# path's linear-in-b wire model.
-_RING_SAT = 4
-
-
-def _ring_budgeted(hw: HW, comm_budget: Optional[float]) -> Tuple[HW, HW]:
-    """(hw_compute, hw_comm) for the ring modes, priced from lanes."""
-    b = 1.0 if comm_budget is None else comm_budget
-    if not (0.0 < b <= 1.0):
-        raise ValueError(f"comm_budget must be in (0, 1], got {b}")
-    c = max(1, ring_channels(b))
-    hw_comm = dataclasses.replace(hw, ici=hw.ici * min(1.0, c / _RING_SAT))
-    b_eff = c / MAX_RING_CHANNELS
-    mfu = hw.mfu_cap * (1.0 - _BUDGET_TAX * b_eff) / (1.0 - _BUDGET_TAX)
-    hw_compute = dataclasses.replace(hw, mfu_cap=mfu)
-    return hw_compute, hw_comm
-
-
 def layer_ops(cfg: ModelConfig, mode: str, tokens: int, ctx: int, tp: int,
-              hw: HW, n_layers: int = 4, smart: bool = True,
-              split: Optional[Tuple[int, int]] = None,
-              comm_budget: Optional[float] = None
+              hw: HW, n_layers: int = 4, smart: bool = True
               ) -> List[Op]:
-    """Build the op list for `n_layers` consecutive layers.
-
-    ``split`` pins the tokenweave split point explicitly (a tuned plan's
-    ``plan_split``); None keeps the built-in smart/naive split.
-    ``comm_budget`` applies the §14 resource-budget contention model;
-    None / 1.0 is the legacy full-budget pricing, bit-exact."""
+    """Build the op list for `n_layers` consecutive layers."""
     d = cfg.d_model
     n = tp
     ops: List[Op] = []
     ring = mode in ("ring", "ringweave")
-    hwc, hwm = (_ring_budgeted if ring else _budgeted)(hw, comm_budget)
 
     def comm_block(tag: str, t: int, deps) -> Tuple[str, List[Op]]:
         """the AR(+norm) slot; returns (terminal op name, ops)."""
         if ring:
             # one-kernel ring RS+norm+AG: norm never leaves VMEM
-            dur = (2 * t_rs_or_ag(t, d, n, hwm)
-                   + t_ring_norm(max(t // n, 1), d, hwm))
+            dur = (2 * t_rs_or_ag(t, d, n, hw)
+                   + t_ring_norm(max(t // n, 1), d, hw))
             o = Op(f"ring{tag}", "comm", dur, tuple(deps))
             return o.name, [o]
         if mode == "nocomm":
-            o = Op(f"norm{tag}", "compute", t_norm(t, d, hwc, fused=False),
+            o = Op(f"norm{tag}", "compute", t_norm(t, d, hw, fused=False),
                    tuple(deps))
             return o.name, [o]
         if mode == "vanilla":
-            a = Op(f"ar{tag}", "comm", t_allreduce(t, d, n, hwm), tuple(deps))
-            b = Op(f"norm{tag}", "compute", t_norm(t, d, hwc, fused=False),
+            a = Op(f"ar{tag}", "comm", t_allreduce(t, d, n, hw), tuple(deps))
+            b = Op(f"norm{tag}", "compute", t_norm(t, d, hw, fused=False),
                    (a.name,))
             return b.name, [a, b]
         if mode == "reordered":
-            a = Op(f"rs{tag}", "comm", t_rs_or_ag(t, d, n, hwm), tuple(deps))
+            a = Op(f"rs{tag}", "comm", t_rs_or_ag(t, d, n, hw), tuple(deps))
             b = Op(f"norm{tag}", "compute",
-                   t_norm(max(t // n, 1), d, hwc, fused=False), (a.name,))
-            c = Op(f"ag{tag}", "comm", t_rs_or_ag(t, d, n, hwm), (b.name,))
+                   t_norm(max(t // n, 1), d, hw, fused=False), (a.name,))
+            c = Op(f"ag{tag}", "comm", t_rs_or_ag(t, d, n, hw), (b.name,))
             return c.name, [a, b, c]
         # fused kernel: RS + single-pass norm on t/N + AG as ONE comm op
-        dur = (2 * t_rs_or_ag(t, d, n, hwm)
-               + t_norm(max(t // n, 1), d, hwm, fused=True))
+        dur = (2 * t_rs_or_ag(t, d, n, hw)
+               + t_norm(max(t // n, 1), d, hw, fused=True))
         o = Op(f"fused{tag}", "comm", dur, tuple(deps))
         return o.name, [o]
 
@@ -279,11 +220,11 @@ def layer_ops(cfg: ModelConfig, mode: str, tokens: int, ctx: int, tp: int,
         prev = ()
         for i in range(n_layers):
             at = Op(f"attn{i}", "compute",
-                    t_attn_layer(cfg, tokens, ctx, tp, hwc), prev)
+                    t_attn_layer(cfg, tokens, ctx, tp, hw), prev)
             ops.append(at)
             t1, blk = comm_block(f"_a{i}", tokens, [at.name])
             ops += blk
-            ff = Op(f"ffn{i}", "compute", t_ffn_layer(cfg, tokens, tp, hwc),
+            ff = Op(f"ffn{i}", "compute", t_ffn_layer(cfg, tokens, tp, hw),
                     (t1,))
             ops.append(ff)
             t2, blk2 = comm_block(f"_f{i}", tokens, [ff.name])
@@ -292,27 +233,26 @@ def layer_ops(cfg: ModelConfig, mode: str, tokens: int, ctx: int, tp: int,
         return ops
 
     assert mode in ("tokenweave", "ringweave")
-    if split is None:
-        split = smart_split(tokens, hw.tile) if smart else naive_split(tokens)
+    split = smart_split(tokens, hw.tile) if smart else naive_split(tokens)
     if split is None:
         return layer_ops(cfg, "ring" if ring else "fuseonly", tokens, ctx,
-                         tp, hw, n_layers, comm_budget=comm_budget)
+                         tp, hw, n_layers)
     t0, t1v = split
     cache_ctx = max(ctx - tokens, 0)   # pre-existing (chunked-prefill) kv
     prev = {0: (), 1: ()}
     for i in range(n_layers):
         # paper Fig 8 order; suffix attends prefix's kv -> dep on attn0
         a0 = Op(f"attn0_{i}", "compute",
-                t_attn_layer(cfg, t0, cache_ctx + t0, tp, hwc),
+                t_attn_layer(cfg, t0, cache_ctx + t0, tp, hw),
                 prev[0])
         c0, blk0 = comm_block(f"_a0{i}", t0, [a0.name])
         a1 = Op(f"attn1_{i}", "compute",
-                t_attn_layer(cfg, t1v, cache_ctx + tokens, tp, hwc),
+                t_attn_layer(cfg, t1v, cache_ctx + tokens, tp, hw),
                 prev[1] + (a0.name,))
         c1, blk1 = comm_block(f"_a1{i}", t1v, [a1.name])
-        f0 = Op(f"ffn0_{i}", "compute", t_ffn_layer(cfg, t0, tp, hwc), (c0,))
+        f0 = Op(f"ffn0_{i}", "compute", t_ffn_layer(cfg, t0, tp, hw), (c0,))
         d0, blkd0 = comm_block(f"_f0{i}", t0, [f0.name])
-        f1 = Op(f"ffn1_{i}", "compute", t_ffn_layer(cfg, t1v, tp, hwc), (c1,))
+        f1 = Op(f"ffn1_{i}", "compute", t_ffn_layer(cfg, t1v, tp, hw), (c1,))
         d1, blkd1 = comm_block(f"_f1{i}", t1v, [f1.name])
         ops += [a0, a1, f0, f1] + blk0 + blk1 + blkd0 + blkd1
         prev = {0: (d0,), 1: (d1,)}
@@ -321,15 +261,12 @@ def layer_ops(cfg: ModelConfig, mode: str, tokens: int, ctx: int, tp: int,
 
 def layer_latency(cfg: ModelConfig, mode: str, tokens: int, *, tp: int = 8,
                   ctx: Optional[int] = None, hw: Optional[HW] = None,
-                  n_layers: int = 4, smart: bool = True,
-                  split: Optional[Tuple[int, int]] = None,
-                  comm_budget: Optional[float] = None) -> float:
+                  n_layers: int = 4, smart: bool = True) -> float:
     """Steady-state per-layer latency (simulate n_layers, divide)."""
     hw = hw or HW()
     ctx = ctx if ctx is not None else tokens
     total, _ = simulate(layer_ops(cfg, mode, tokens, ctx, tp, hw,
-                                  n_layers=n_layers, smart=smart,
-                                  split=split, comm_budget=comm_budget))
+                                  n_layers=n_layers, smart=smart))
     return total / n_layers
 
 
@@ -341,9 +278,7 @@ def e2e_latency(cfg: ModelConfig, mode: str, tokens: int, *,
 
 def step_attribution(cfg: ModelConfig, mode: str, tokens: int, *,
                      tp: int = 8, ctx: Optional[int] = None,
-                     hw: Optional[HW] = None, n_layers: int = 4,
-                     split: Optional[Tuple[int, int]] = None,
-                     comm_budget: Optional[float] = None
+                     hw: Optional[HW] = None, n_layers: int = 4
                      ) -> Dict[str, float]:
     """Per-forward compute/comm/overlap attribution (DESIGN.md §12).
 
@@ -361,16 +296,10 @@ def step_attribution(cfg: ModelConfig, mode: str, tokens: int, *,
     ``hw.overhead`` (the fixed per-dispatch cost fitted by
     analysis/calibration.py, DESIGN.md §13) is added once to the makespan
     — it is neither compute- nor comm-stream time, so the busy totals and
-    the overlapped term are unaffected.
-
-    ``split`` / ``comm_budget`` price a tuned plan's explicit split point
-    and resource budget (DESIGN.md §14); the defaults keep the legacy
-    smart-split full-budget pricing bit-exact — this is what the
-    ``analysis/autotune.py`` offline search evaluates per candidate."""
+    the overlapped term are unaffected."""
     hw = hw or HW()
     ctx = ctx if ctx is not None else tokens
-    ops = layer_ops(cfg, mode, tokens, ctx, tp, hw, n_layers=n_layers,
-                    split=split, comm_budget=comm_budget)
+    ops = layer_ops(cfg, mode, tokens, ctx, tp, hw, n_layers=n_layers)
     makespan, _ = simulate(ops)
     busy = {"compute": 0.0, "comm": 0.0}
     for op in ops:

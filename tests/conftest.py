@@ -84,14 +84,20 @@ def tiny_engine_builder(tiny_model):
     same scheduler/sampling signature share a jit cache, so replaying many
     short traces (the differential harness, lifecycle tests) compiles each
     step shape once per configuration instead of once per engine."""
+    import dataclasses
+
     from repro.runtime.engine import Engine
     from repro.runtime.scheduler import SchedulerConfig
 
     jit_caches = {}
 
     def build(*, draft=None, seed=0, temperature=0.0, top_k=0, top_p=1.0,
-              obs=None, obs_track="engine", profiler=None, **scfg_kw):
+              obs=None, obs_track="engine", profiler=None, pcfg=None,
+              **scfg_kw):
         api, mesh, params = tiny_model
+        if pcfg is not None:
+            # same weights, another ParallelConfig (comm mode, weave)
+            api = dataclasses.replace(api, pcfg=pcfg)
         scfg_kw.setdefault("max_batch", 4)
         scfg_kw.setdefault("chunk_tokens", 48)
         scfg_kw.setdefault("max_len", 128)
@@ -99,7 +105,8 @@ def tiny_engine_builder(tiny_model):
         # obs/profiler are deliberately NOT in the jit-cache key: tracing
         # and measured-time profiling must not change compilation (on/off
         # identity, DESIGN.md §12/§13)
-        key = tuple(sorted(scfg_kw.items())) + (temperature, top_k, top_p)
+        key = tuple(sorted(scfg_kw.items())) + (temperature, top_k, top_p,
+                                                repr(pcfg))
         cache = jit_caches.setdefault(key, {})
         return Engine(api, mesh, params, SchedulerConfig(**scfg_kw),
                       temperature=temperature, top_k=top_k, top_p=top_p,

@@ -8,21 +8,27 @@ replayed through FIVE engine configurations:
   * two-dispatch over the paged block pool,
   * packed hybrid batching over the paged block pool,
   * two-dispatch over legacy slots,
-  * packed over the paged pool with an all-``fused`` overlap plan (the
-    ring AllReduce-RMSNorm hot path, DESIGN.md §2/§14),
-  * two-dispatch over legacy slots with the same fused plan,
+  * packed over the paged pool with ``comm_mode="ring"`` (the ring
+    AllReduce-RMSNorm hot path, DESIGN.md §2),
+  * two-dispatch over legacy slots in the same ring mode,
 
 asserting greedy token-IDENTITY across all five for every surviving
-request — packed-fused vs packed-weave vs two-dispatch, on both KV
+request — packed-ring vs packed-fused vs two-dispatch, on both KV
 backends — plus invariant sweeps at every step and at end of trace:
 
   * ``PackedPlan.total_tokens <= chunk_tokens`` (the §6 budget),
   * a cache slot is only ever reassigned after its owner finished,
   * block refcounts return to zero and every table is released.
 
+A second sweep replays other seeded traces through weave-on and
+weave-off (``tokenweave=False``) engines on both KV backends: the weave
+rule reshapes HOW a forward overlaps, never what it computes.
+
 The harness must CATCH faults, not just pass: the last tests inject a
 skipped block release / a budget overrun and assert the sweep trips.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -31,27 +37,6 @@ from repro.runtime.requests import Request, State
 from repro.runtime.scheduler import PackedPlan
 
 N_TRACES = 25
-
-
-@pytest.fixture(scope="session")
-def fused_plan_path(tmp_path_factory):
-    """An overlap plan forcing method=``fused`` (ring kernel + weave,
-    half the ring-lane budget) at EVERY tiny/tp1 site and bucket, so the
-    fused engine columns exercise the plan-forced ring comm path — which
-    on this backend walks the fallback ladder, and must stay
-    token-identical either way."""
-    from repro.core.policy import PLAN_VERSION, PlanEntry, SITES, TunedPolicy
-    from repro.core.splitting import DEFAULT_BUCKET_EDGES, token_bucket
-    buckets = {token_bucket(lo, DEFAULT_BUCKET_EDGES)
-               for lo in DEFAULT_BUCKET_EDGES} | {token_bucket(0)}
-    entries = tuple(PlanEntry(site=site, bucket=b, tp=1, family="dense",
-                              method="fused", split_frac=0.5, budget=0.5)
-                    for site in SITES for b in sorted(buckets))
-    plan = TunedPolicy(plan_id=424242, version=PLAN_VERSION,
-                       bucket_edges=DEFAULT_BUCKET_EDGES, entries=entries)
-    path = tmp_path_factory.mktemp("plans") / "all_fused.json"
-    plan.save(str(path))
-    return str(path)
 
 
 # --------------------------------------------------------------------------
@@ -151,34 +136,67 @@ def _check_end_state(eng):
 # --------------------------------------------------------------------------
 
 @pytest.mark.parametrize("trial", range(N_TRACES))
-def test_differential_trace(trial, tiny_engine_builder, fused_plan_path):
+def test_differential_trace(trial, tiny_engine_builder, tiny_pcfg):
     rng = np.random.RandomState(1000 + trial)
     prompts, outs, gamma, cancels = _gen_trace(rng)
     kw = dict(max_batch=3, chunk_tokens=48, max_len=128, prefill_bucket=16,
               block_size=16, spec_gamma=gamma)
+    ring = dataclasses.replace(tiny_pcfg, comm_mode="ring")
 
     results = {}
     for name, cfg in (
             ("two_paged", dict(paged=True, packed=False)),
             ("packed_paged", dict(paged=True, packed=True)),
             ("two_legacy", dict(paged=False, packed=False)),
-            # the fused-path columns: the same traces with the all-fused
-            # overlap plan installed, on both KV backends
-            ("packed_fused", dict(paged=True, packed=True,
-                                  plan_path=fused_plan_path)),
-            ("two_legacy_fused", dict(paged=False, packed=False,
-                                      plan_path=fused_plan_path))):
+            # the ring-mode columns: the same traces with every comm_norm
+            # site configured for the ring kernel, on both KV backends
+            ("packed_ring", dict(paged=True, packed=True, pcfg=ring)),
+            ("two_legacy_ring", dict(paged=False, packed=False,
+                                     pcfg=ring))):
         eng = tiny_engine_builder(**kw, **cfg)
         results[name] = _drive(eng, prompts, outs, cancels)
 
     ref = results["two_paged"]
-    for name in ("packed_paged", "two_legacy", "packed_fused",
-                 "two_legacy_fused"):
+    for name in ("packed_paged", "two_legacy", "packed_ring",
+                 "two_legacy_ring"):
         assert results[name] == ref, (
             trial, gamma, cancels, name, results[name], ref)
     # every surviving request ran to its full budget
     for rid, out in ref.items():
         assert len(out) == outs[rid]
+
+
+# --------------------------------------------------------------------------
+# weave on vs off (DESIGN.md §14): the split changes no token
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("trial", range(N_TRACES))
+def test_weave_differential_trace(trial, tiny_engine_builder, tiny_pcfg):
+    """Seeded random traces (mixed prefill, shared prefixes, spec
+    windows, cancellations) through weave-on and weave-off engines on
+    BOTH KV backends: greedy token-identity across all four, with the
+    same invariant sweeps (packed budget, slot reuse, block refcounts)
+    riding along."""
+    rng = np.random.RandomState(9000 + trial)
+    prompts, outs, gamma, cancels = _gen_trace(rng)
+    kw = dict(max_batch=3, chunk_tokens=48, max_len=128, prefill_bucket=16,
+              block_size=16, spec_gamma=gamma)
+    off = dataclasses.replace(tiny_pcfg, tokenweave=False)
+
+    results = {}
+    for name, cfg in (
+            ("weave_paged", dict(paged=True)),
+            ("plain_paged", dict(paged=True, pcfg=off)),
+            ("weave_slots", dict(paged=False)),
+            ("plain_slots", dict(paged=False, pcfg=off))):
+        eng = tiny_engine_builder(**kw, **cfg)
+        results[name] = _drive(eng, prompts, outs, cancels)
+        if name.startswith("plain"):
+            assert eng.stats.weave_forwards == 0, name
+
+    ref = results["weave_paged"]
+    for name in ("plain_paged", "weave_slots", "plain_slots"):
+        assert results[name] == ref, (trial, gamma, cancels, name)
 
 
 # --------------------------------------------------------------------------

@@ -10,16 +10,11 @@ unfused vanilla composition, so the numerics hold identically whichever
 rung fires (on CPU the Pallas interpreter drives the real kernel).
 
 Also here: the fault-injection half of the tier — a planted
-wrong-chunk-ownership ring schedule must be caught by the numerics pin,
-and a budget-overcommitting plan entry (a budget that rounds to zero
-ring lanes) must be caught by scripts/check_plan.py — plus the
-engine-level integration: loading the committed fused plan changes no
-token and surfaces per-site ``engine/site_fused_rate`` gauges.
+wrong-chunk-ownership ring schedule must be caught by the numerics pin —
+plus the engine-level integration: an engine configured with
+``comm_mode="ring"`` emits the same tokens as the ``fused`` one.
 """
 import dataclasses
-import importlib.util
-import json
-import os
 
 import jax
 import jax.numpy as jnp
@@ -30,18 +25,9 @@ from jax.sharding import PartitionSpec as P
 from conftest import run_distributed
 from repro.configs.base import ModelConfig, ParallelConfig
 from repro.core import fused_collectives as fc
-from repro.core.splitting import MAX_RING_CHANNELS, ring_channels
 from repro.distributed.context import CommCtx
 from repro.kernels import ref as KREF
 from repro.kernels.ref import ring_ar_rmsnorm_ref
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-DEFAULT_PLAN = os.path.join(REPO, "benchmarks", "plans", "default.json")
-
-_CHECK_PLAN = os.path.join(REPO, "scripts", "check_plan.py")
-_spec = importlib.util.spec_from_file_location("check_plan", _CHECK_PLAN)
-check_plan = importlib.util.module_from_spec(_spec)
-_spec.loader.exec_module(check_plan)
 
 
 def _mesh11():
@@ -49,10 +35,9 @@ def _mesh11():
                          axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
-def _comm_norm_tp1(mode, x, res, w, *, budget=1.0, use_pallas=True):
+def _comm_norm_tp1(mode, x, res, w, *, use_pallas=True):
     """Run comm_norm on the 1-device mesh (the exact hot-path call)."""
-    ctx = CommCtx(mode=mode, use_pallas=use_pallas, interpret=use_pallas,
-                  comm_budget=budget)
+    ctx = CommCtx(mode=mode, use_pallas=use_pallas, interpret=use_pallas)
 
     def f(xsh, r):
         return fc.comm_norm(xsh[0], r, w, ctx=ctx)
@@ -64,13 +49,13 @@ def _comm_norm_tp1(mode, x, res, w, *, budget=1.0, use_pallas=True):
     return g(x[None], res)
 
 
-def _check_ring_vs_ref_tp1(t, d, dtype, *, budget=1.0, tol=None):
+def _check_ring_vs_ref_tp1(t, d, dtype, *, tol=None):
     key = jax.random.PRNGKey(t * 1000 + d)
     x = jax.random.normal(key, (t, d), dtype)
     res = jax.random.normal(jax.random.PRNGKey(1), (t, d), dtype)
     w = (jnp.abs(jax.random.normal(jax.random.PRNGKey(2), (d,))) + 0.5
          ).astype(dtype)
-    o_ring, r_ring = _comm_norm_tp1("ring", x, res, w, budget=budget)
+    o_ring, r_ring = _comm_norm_tp1("ring", x, res, w)
     o_van, r_van = _comm_norm_tp1("vanilla", x, res, w, use_pallas=False)
     ref_outs, ref_res = ring_ar_rmsnorm_ref([x], [res], w)
     tol = tol if tol is not None else (1e-5 if dtype == jnp.float32
@@ -93,32 +78,29 @@ def test_comm_norm_ring_matches_ref_and_vanilla_tp1(t, d, dtype):
     _check_ring_vs_ref_tp1(t, d, dtype)
 
 
-@pytest.mark.parametrize("budget", [0.125, 0.5, 1.0])
-def test_comm_norm_ring_budget_does_not_change_numerics(budget):
-    """The ring-lane grant is a RESOURCE knob; any budget in (0, 1] must
-    produce bit-compatible results (only throughput may differ)."""
-    _check_ring_vs_ref_tp1(32, 64, jnp.float32, budget=budget)
-
-
 # --------------------------------------------------------------------------
 # the ragged fallback edge: t_local % tp != 0 gates ring -> vanilla
 # --------------------------------------------------------------------------
 
 def test_comm_ctx_ragged_falls_back_to_vanilla():
-    from repro.models.transformer import _comm_ctx
+    from repro.models.transformer import _comm_ctx, comm_mode_for
     cfg = ModelConfig(name="tiny", family="dense", num_layers=1, d_model=64,
                       num_heads=4, num_kv_heads=2, head_dim=16, d_ff=128,
                       vocab_size=128, dtype="float32")
-    pcfg = ParallelConfig()
-    # divisible: the plan-forced ring mode goes through, budget and all
-    ctx = _comm_ctx(pcfg, cfg, 32, 4, mode="ring", budget=0.5)
-    assert ctx.mode == "ring" and ctx.comm_budget == 0.5
+    pcfg = ParallelConfig(comm_mode="ring")
+    # divisible: the configured ring mode goes through
+    assert comm_mode_for(pcfg, 32, 4) == "ring"
+    assert _comm_ctx(pcfg, cfg, 32, 4).mode == "ring"
     # ragged (t_local % tp != 0): token-sharded layouts are impossible
-    assert _comm_ctx(pcfg, cfg, 33, 4, mode="ring").mode == "vanilla"
+    assert comm_mode_for(pcfg, 33, 4) == "vanilla"
+    assert _comm_ctx(pcfg, cfg, 33, 4).mode == "vanilla"
     # degenerate (t_local < tp): same fallback
-    assert _comm_ctx(pcfg, cfg, 3, 4, mode="ring").mode == "vanilla"
-    # no plan override: pcfg.comm_mode rules, as before
-    assert _comm_ctx(pcfg, cfg, 32, 4).mode == pcfg.comm_mode
+    assert comm_mode_for(pcfg, 3, 4) == "vanilla"
+    # tp=1 divides every token count: ring stays ring
+    assert comm_mode_for(pcfg, 7, 1) == "ring"
+    # the default fused mode falls back the same way
+    assert comm_mode_for(ParallelConfig(), 32, 4) == "fused"
+    assert comm_mode_for(ParallelConfig(), 33, 4) == "vanilla"
 
 
 # --------------------------------------------------------------------------
@@ -145,7 +127,7 @@ for dtype, tol in ((jnp.float32, 2e-5), (jnp.bfloat16, 3e-2)):
          ).astype(dtype)
     def run(mode, use_pallas):
         ctx = CommCtx(mode=mode, use_pallas=use_pallas,
-                      interpret=use_pallas, comm_budget=0.5)
+                      interpret=use_pallas)
         sharded = mode != 'vanilla'
         def f(xsh, r):
             return fc.comm_norm(xsh[0], r if sharded else r[0], w, ctx=ctx)
@@ -232,66 +214,39 @@ def test_numerics_pin_catches_wrong_chunk_ownership(monkeypatch):
                    for b, g in zip(bad_res, good_res))
 
 
-def test_check_plan_rejects_budget_overcommit():
-    """Planted fault: a fused plan entry whose budget rounds to ZERO ring
-    lanes (an over-committed comm grant the kernel could never honor).
-    ``PlanEntry.validate`` accepts any budget in (0, 1], so only the
-    check_plan structural gate stands between this entry and the engine."""
-    with open(DEFAULT_PLAN) as f:
-        doc = json.load(f)
-    assert check_plan.check_plan(doc) == []      # the committed plan is clean
-    fused_idx = next(i for i, e in enumerate(doc["entries"])
-                     if e["method"] in ("fused", "fused-unsplit"))
-    bad = json.loads(json.dumps(doc))
-    bad["entries"][fused_idx]["budget"] = 0.05   # ring_channels -> 0 lanes
-    assert ring_channels(0.05) == 0
-    failures = check_plan.check_plan(bad)
-    assert failures and any("ring lanes" in f for f in failures)
-
-
-def test_ring_channels_budget_mapping():
-    """budget -> lane-count contract (the paper's 2-8 SM knob)."""
-    assert ring_channels(1.0) == MAX_RING_CHANNELS
-    assert ring_channels(0.5) == MAX_RING_CHANNELS // 2
-    assert ring_channels(1.0 / MAX_RING_CHANNELS) == 1
-    assert ring_channels(0.05) == 0      # deliberately NOT clamped: the
-    #                                      plan gate must see the fault
-
-
 # --------------------------------------------------------------------------
-# engine integration: the committed fused plan is dispatchable end-to-end
+# engine integration: the ring mode is dispatchable end-to-end
 # --------------------------------------------------------------------------
 
 def test_engine_fused_plan_token_identity_and_fused_rate(
-        tiny_engine_builder):
-    """Loading the committed plan (whose tiny/tp1 entries are all
-    fused/fused-unsplit) must change NO token vs the plan-free engine —
-    the fallback ladder lands on numerically-identical rungs — while the
-    per-site ``engine/site_fused_rate`` gauges surface that the fused
-    path was selected at every decided site."""
+        tiny_engine_builder, tiny_pcfg):
+    """An engine configured with ``comm_mode="ring"`` must emit the same
+    tokens as the ``fused`` one — the fallback ladder lands on
+    numerically-identical rungs — and every dispatch runs its
+    ``comm_norm`` sites in the configured mode."""
     from repro.runtime.requests import Request
 
-    def run(plan_path):
-        eng = tiny_engine_builder(paged=True, packed=True,
-                                  plan_path=plan_path)
+    def run(mode):
+        pcfg = dataclasses.replace(tiny_pcfg, comm_mode=mode)
+        eng = tiny_engine_builder(paged=True, packed=True, pcfg=pcfg)
+        comms = set()
+        dispatch = eng._dispatch
+
+        def noting(fn, args, info, comm):
+            comms.add(comm)
+            return dispatch(fn, args, info, comm)
+
+        eng._dispatch = noting
         for i in range(3):
             # ragged prompt lengths: some forwards hit the t % tp edge
             eng.add_request(Request(rid=i, prompt=list(range(19 + 7 * i)),
                                     max_new_tokens=6))
         eng.run()
         outs = {r.rid: r.output for r in eng.sched.finished}
-        return outs, eng.metrics_snapshot()
+        return outs, comms
 
-    base_outs, _ = run(None)
-    plan_outs, snap = run(DEFAULT_PLAN)
-    assert plan_outs == base_outs
-    rates = {k: v for k, v in snap.items()
-             if k.startswith("engine/site_fused_rate")}
-    assert rates, f"no site_fused_rate gauges in {sorted(snap)}"
-    assert all(v == 1.0 for v in rates.values()), rates
-    # and without a plan there is no fused routing at all (the derived
-    # rate gauges exist either way; they must read zero)
-    _, base_snap = run(None)
-    base_rates = {k: v for k, v in base_snap.items()
-                  if k.startswith("engine/site_fused_rate")}
-    assert all(v == 0.0 for v in base_rates.values()), base_rates
+    fused_outs, fused_comms = run("fused")
+    ring_outs, ring_comms = run("ring")
+    assert ring_outs == fused_outs
+    # tp=1 divides every token count, so no dispatch falls back
+    assert fused_comms == {"fused"} and ring_comms == {"ring"}

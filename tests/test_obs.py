@@ -87,7 +87,7 @@ def test_percentile_matches_linear_interpolation():
 def _forward_args(**over):
     a = dict(kind="prefill", weave=True, reason="split", tokens=64,
              tokens_real=64, threshold=32, split=[32, 32],
-             method="tokenweave", plan_id=0, bucket="64-127",
+             method="tokenweave",
              est_compute=1.0, est_comm=0.5, est_overlapped=0.4)
     a.update(over)
     return a

@@ -61,39 +61,3 @@ def test_split_sizes_for_batch(n, unit, rows, min_tokens):
 def test_pad_to_multiple(n, m):
     p = pad_to_multiple(n, m)
     assert p >= n and p % m == 0 and p - n < m
-
-
-@given(n=st.integers(1, 500_000), unit=st.integers(1, 512),
-       rows=st.integers(1, 64), min_tokens=st.integers(0, 4096),
-       site=st.sampled_from(("prefill", "decode", "verify", "packed")),
-       tp=st.integers(1, 16))
-@settings(max_examples=300, deadline=None)
-def test_threshold_policy_is_split_decision(n, unit, rows, min_tokens,
-                                            site, tp):
-    """DESIGN.md §14: the degenerate ThresholdPolicy must be the legacy
-    global-threshold decision FIELD-FOR-FIELD, for every site/tp key —
-    engines without a tuned plan cannot change behavior."""
-    from repro.core.policy import ThresholdPolicy
-    from repro.core.splitting import split_decision
-    got = ThresholdPolicy().decide(site, n, unit=unit,
-                                   min_tokens=min_tokens,
-                                   row_multiple=rows, tp=tp)
-    assert got == split_decision(n, unit=unit, min_tokens=min_tokens,
-                                 row_multiple=rows)
-
-
-@given(n=st.integers(1, 500_000), unit=st.integers(1, 1024),
-       frac=st.floats(0.01, 0.99))
-@settings(max_examples=300, deadline=None)
-def test_plan_split_conserves_waves(n, unit, frac):
-    """The tuner's parameterized split keeps the paper's wave-conservation
-    property at EVERY fraction, and frac=0.5 is exactly smart_split."""
-    from repro.core.splitting import plan_split
-    s = plan_split(n, unit, frac)
-    if s is None:
-        assert n < 2 * unit
-        return
-    l1, l2 = s
-    assert l1 + l2 == n and l1 > 0 and l2 > 0 and l1 % unit == 0
-    assert wave_count(l1, unit) + wave_count(l2, unit) == wave_count(n, unit)
-    assert plan_split(n, unit, 0.5) == smart_split(n, unit)
